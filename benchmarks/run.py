@@ -51,6 +51,9 @@ def main() -> None:
     ap.add_argument("--skip-kernels", action="store_true",
                     help="skip the fused-kernel benches")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows = []
     serve_rows = []
     dist_rows = []

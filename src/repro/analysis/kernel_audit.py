@@ -146,8 +146,10 @@ def _tile_violations(label, block, elem_bytes, full):
 
     Per dim: a known array dim must be evenly tiled; the lane (last) dim
     must be a LANE multiple unless the block covers the whole dim; the
-    sublane (2nd-last) dim must be a sublane(dtype) multiple unless it is
-    1 or covers the whole dim.  Leading dims only need to divide.
+    sublane (2nd-last) dim must be a sublane(dtype) multiple unless it
+    covers the whole dim (a 1 there is refused too: v5e's compiler
+    rejected a ``(1, 1)`` block over a ``(K, 1)`` array).  Leading dims
+    only need to divide.
     """
     out = []
     nd = len(block)
@@ -173,7 +175,7 @@ def _tile_violations(label, block, elem_bytes, full):
                 out.append(f"{label}: lane dim {b} is not a multiple of "
                            f"{LANE} (and does not span the array dim)")
         elif axis == nd - 2:
-            if b % sub != 0 and b != 1 and not whole:
+            if b % sub != 0 and not whole:
                 out.append(f"{label}: sublane dim {b} is not a multiple "
                            f"of {sub} for {elem_bytes}-byte elements")
     return out
@@ -194,8 +196,8 @@ def _block_layout(kind, blocks, n_digits, res_bytes, dims):
     g = d.get
     if kind == "rns_matmul":
         bm, bn, bk = blocks["bm"], blocks["bn"], blocks["bk"]
+        # the moduli sit whole in SMEM: no VMEM tile
         tiles = [
-            ("moduli", (1, 1), 4, (K, 1)),
             ("a_res", (1, bm, bk), res_bytes, (K, g("M"), g("D"))),
             ("b_res", (1, bk, bn), res_bytes, (K, g("D"), g("N"))),
             ("out", (1, bm, bn), 4, (K, g("M"), g("N"))),
@@ -204,7 +206,6 @@ def _block_layout(kind, blocks, n_digits, res_bytes, dims):
     elif kind == "rns_fused_encode_matmul":
         bm, bn, bk = blocks["bm"], blocks["bn"], blocks["bk"]
         tiles = [
-            ("moduli", (1, 1), 4, (K, 1)),
             ("x", (bm, bk), 4, (g("M"), g("D"))),
             ("scale", (bm, 1), 4, (g("M"), 1)),
             ("b_res", (1, bk, bn), res_bytes, (K, g("D"), g("N"))),
@@ -352,7 +353,8 @@ class KernelLaunch:
     kernel_name: str
     profile: str | None
     grid: tuple
-    in_specs: tuple    # ((block_shape, index_map), ...)
+    in_specs: tuple    # ((block_shape, index_map), ...); (None, None)
+                       # for an operand placed whole outside VMEM
     out_specs: tuple
     operands: tuple    # ((shape, dtype_str, itemsize), ...)
     outs: tuple
@@ -398,6 +400,17 @@ def _kernel_identity(fn):
                         else getattr(prof, "name", None))
 
 
+def _spec_entry(spec):
+    """``(block_shape, index_map)`` of a BlockSpec; ``(None, None)`` for a
+    memory-space spec with no block shape, which places the whole operand
+    (e.g. the moduli in SMEM) instead of streaming tiles of it.  Such a
+    spec is never in VMEM: the lint's ``whole-array-vmem`` rule refuses
+    whole-array VMEM specs."""
+    if spec.block_shape is None:
+        return None, None
+    return tuple(spec.block_shape), spec.index_map
+
+
 def capture_launches(fn, *args, **kwargs) -> list[KernelLaunch]:
     """Abstract-interpret ``fn`` (zero FLOPs) recording every pallas_call.
 
@@ -420,12 +433,10 @@ def capture_launches(fn, *args, **kwargs) -> list[KernelLaunch]:
             out_shape = fargs[0]
         kind, kname, prof = _kernel_identity(kernel)
         grid_t = (grid,) if isinstance(grid, int) else tuple(grid or ())
-        ins = tuple((tuple(s.block_shape), s.index_map)
-                    for s in (in_specs or []))
+        ins = tuple(_spec_entry(s) for s in (in_specs or []))
         out_spec_list = (list(out_specs) if isinstance(out_specs, (list, tuple))
                          else [out_specs])
-        outs_t = tuple((tuple(s.block_shape), s.index_map)
-                       for s in out_spec_list if s is not None)
+        outs_t = tuple(_spec_entry(s) for s in out_spec_list if s is not None)
         scratch = tuple((tuple(s.shape), jnp.dtype(s.dtype).itemsize)
                         for s in (scratch_shapes or []))
         out_leaves = jax.tree_util.tree_leaves(out_shape)
@@ -529,6 +540,8 @@ def check_launch(launch: KernelLaunch) -> list[str]:
     points, exhaustive = _grid_points(launch.grid)
     for i, ((block, imap), (shape, _dt, eb)) in enumerate(
             zip(launch.in_specs, launch.operands)):
+        if block is None:   # placed whole: no tiles, nothing to cover
+            continue
         out.extend(_check_spec(kind, f"in{i}", block, imap, shape, eb,
                                launch.grid, points, exhaustive, False))
     for i, ((block, imap), (shape, _dt, eb)) in enumerate(
@@ -556,12 +569,14 @@ def check_launch(launch: KernelLaunch) -> list[str]:
 
 
 def launch_vmem_bytes(launch: KernelLaunch) -> int:
-    """Double-buffered working set of a captured launch, in bytes."""
+    """Double-buffered working set of a captured launch, in bytes
+    (operands placed whole outside VMEM take none)."""
     streamed = sum(
         _prod(block) * eb
         for (block, _), (_, _, eb) in
         list(zip(launch.in_specs, launch.operands))
-        + list(zip(launch.out_specs, launch.outs)))
+        + list(zip(launch.out_specs, launch.outs))
+        if block is not None)
     return 2 * streamed + sum(_prod(s) * eb for s, eb in launch.scratch)
 
 

@@ -20,8 +20,10 @@ Rules (ids are the suppression keys):
 * ``whole-array-vmem`` — every ``BlockSpec`` names an explicit block
   shape.  A shapeless/None BlockSpec maps the WHOLE operand into VMEM:
   fine for toy shapes, an unbounded-VMEM landmine at serving sizes (see
-  analysis/kernel_audit.py for the budget it would blow).  Approved
-  wrapper files are listed in ``_WHOLE_ARRAY_OK`` (currently none).
+  analysis/kernel_audit.py for the budget it would blow).  A shapeless
+  spec with a ``memory_space=`` other than VMEM (e.g. the moduli in SMEM)
+  places the operand outside VMEM and passes.  Approved wrapper files
+  are listed in ``_WHOLE_ARRAY_OK`` (currently none).
 
 Suppression: ``# lint-ok: <rule>[, <rule>...] [reason]`` on the flagged
 line or the line above; ``# lint-ok-file: <rule>`` anywhere in a file
@@ -133,6 +135,12 @@ class _Checker(ast.NodeVisitor):
             shaped = bool(node.args) and not _none(node.args[0])
             shaped = shaped or any(
                 kw.arg == "block_shape" and not _none(kw.value)
+                for kw in node.keywords)
+            # memory_space=pltpu.SMEM (or HBM/ANY) keeps it out of VMEM
+            shaped = shaped or any(
+                kw.arg == "memory_space"
+                and isinstance(kw.value, ast.Attribute)
+                and kw.value.attr != "VMEM"
                 for kw in node.keywords)
             if not shaped:
                 self.flag(node, "whole-array-vmem",

@@ -372,12 +372,12 @@ def _moduli_arr(p) -> jax.Array:
 
 
 def _jit_shard_map(f, ds, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-
-    mapped = shard_map(f, ds.mesh, in_specs=in_specs, out_specs=out_specs,
-                       check_rep=False, auto=ds.auto_axes())
-    # shard_map with auto (GSPMD-managed) axes only exists under jit; the
-    # wrapper keeps eager call sites working and inlines under outer jits
+    # manual over the digit axis only; every other mesh axis stays with
+    # GSPMD.  Partly-manual shard_map exists only under jit: the wrapper
+    # keeps eager call sites working and inlines under outer jits
+    mapped = jax.shard_map(f, mesh=ds.mesh, in_specs=in_specs,
+                           out_specs=out_specs, axis_names={ds.axis},
+                           check_vma=False)
     return jax.jit(mapped)
 
 

@@ -1,11 +1,1 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-"""Shared Pallas compatibility helpers for the kernel packages."""
-
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-compiler_params = getattr(_pltpu, "CompilerParams", None) or getattr(
-    _pltpu, "TPUCompilerParams"
-)
+"""Pallas TPU kernels for the residue datapath (each with ops.py + ref.py)."""

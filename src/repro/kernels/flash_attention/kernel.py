@@ -18,9 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -97,7 +95,7 @@ def flash_attention_bhtd(q, k, v, *, causal: bool, tk_valid: int,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

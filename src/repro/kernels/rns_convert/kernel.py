@@ -14,9 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.rns import tables
 
@@ -57,7 +55,7 @@ def rns_convert_tiles(
         ],
         out_specs=pl.BlockSpec((K, bt), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((K, T), out_dtype),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

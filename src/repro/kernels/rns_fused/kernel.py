@@ -37,9 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compiler_params
 from repro.kernels.rns_normalize.kernel import mrc_float_tile
 
 from repro.core.rns import tables
@@ -50,9 +49,13 @@ def _quantize_tile(x, s, qmax: int):
     return jnp.clip(jnp.round(x * s), -qmax, qmax).astype(jnp.int32)
 
 
-def _dot_s32(a, b):
+def _dot_s8(a, b):
+    """Residue tiles as int8 operands into an int32 accumulator: the MXU's
+    int8 mode (v5e has no s32 x s32 matmul).  Exact because every residue
+    is < m <= 128."""
     return jax.lax.dot_general(
-        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
+        a.astype(jnp.int8), b.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 # ------------------------------------------------- encode + matmul --------
@@ -64,10 +67,10 @@ def _encode_matmul_kernel(m_ref, x_ref, s_ref, b_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    m = m_ref[0, 0]
+    m = m_ref[pl.program_id(0)]                           # slice modulus
     v = _quantize_tile(x_ref[...], s_ref[...], qmax)      # [bm, bk] int32
     a = jnp.remainder(v, m)                               # digit residues
-    prod = _dot_s32(a, b_ref[0].astype(jnp.int32))
+    prod = _dot_s8(a, b_ref[0])
     # lazy modular reduction: one rem per K step keeps the carry < m
     acc_ref[...] = jnp.remainder(acc_ref[...] + prod, m)
 
@@ -98,7 +101,8 @@ def rns_fused_encode_matmul_tiles(
                           qmax=2 ** (bits - 1) - 1),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda s, i, j, k: (s, 0)),
+            # moduli live whole in scalar memory, indexed by digit slice
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((bm, bk), lambda s, i, j, k: (i, k)),
             pl.BlockSpec((bm, 1), lambda s, i, j, k: (i, 0)),
             pl.BlockSpec((1, bk, bn), lambda s, i, j, k: (s, k, j)),
@@ -106,12 +110,12 @@ def rns_fused_encode_matmul_tiles(
         out_specs=pl.BlockSpec((1, bm, bn), lambda s, i, j, k: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((K, M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(moduli.reshape(-1, 1), x, s_rows, b_res)
+    )(moduli.reshape(-1), x, s_rows, b_res)
 
 
 # ---------------------------------------------- matmul + normalize --------
@@ -127,7 +131,7 @@ def _matmul_normalize_kernel(a_ref, b_ref, o_ref, acc_ref, *, profile,
 
     for j in range(K):
         m = jnp.int32(int(t.moduli[j]))
-        prod = _dot_s32(a_ref[j].astype(jnp.int32), b_ref[j].astype(jnp.int32))
+        prod = _dot_s8(a_ref[j], b_ref[j])
         acc_ref[j] = jnp.remainder(acc_ref[j] + prod, m)
 
     @pl.when(k == n_k - 1)
@@ -158,7 +162,7 @@ def rns_fused_matmul_normalize_tiles(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -180,7 +184,7 @@ def _fused_dot_kernel(x_ref, s_ref, b_ref, o_ref, acc_ref, *, profile,
     for j in range(K):
         m = jnp.int32(int(t.moduli[j]))
         a = jnp.remainder(v, m)
-        prod = _dot_s32(a, b_ref[j].astype(jnp.int32))
+        prod = _dot_s8(a, b_ref[j])
         acc_ref[j] = jnp.remainder(acc_ref[j] + prod, m)
 
     @pl.when(k == n_k - 1)
@@ -214,7 +218,7 @@ def rns_fused_dot_tiles(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((K, bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

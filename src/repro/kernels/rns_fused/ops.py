@@ -27,10 +27,15 @@ from repro.kernels.rns_fused.kernel import (
     rns_fused_encode_matmul_tiles,
     rns_fused_matmul_normalize_tiles,
 )
-from repro.kernels.rns_matmul.ops import _pad_to, _pow2_at_least
+from repro.kernels.rns_matmul.ops import (
+    _pad_to,
+    _pow2_at_least,
+    require_int8_residues,
+)
 
 
 def _blocks(kind, t, shape, bm, bn, bk):
+    require_int8_residues(t)
     if bm is None or bn is None or bk is None:
         from repro.kernels import autotune
 

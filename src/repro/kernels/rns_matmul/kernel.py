@@ -19,9 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(m_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -31,12 +29,14 @@ def _kernel(m_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[0].astype(jnp.int32)          # [bm, bk]
-    b = b_ref[0].astype(jnp.int32)          # [bk, bn]
+    # int8 operands, int32 accumulator: the MXU's int8 mode (v5e has no
+    # s32 x s32 matmul).  Exact because every residue is < m <= 128.
+    a = a_ref[0].astype(jnp.int8)           # [bm, bk]
+    b = b_ref[0].astype(jnp.int8)           # [bk, bn]
     prod = jax.lax.dot_general(
         a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
     )
-    m = m_ref[0, 0]
+    m = m_ref[pl.program_id(0)]             # this digit slice's modulus
     # lazy modular reduction: one rem per K step keeps the carry < m
     acc_ref[...] = jnp.remainder(acc_ref[...] + prod, m)
 
@@ -65,15 +65,16 @@ def rns_matmul_tiles(
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda s, i, j, k: (s, 0)),
+            # moduli live whole in scalar memory, indexed by digit slice
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bm, bk), lambda s, i, j, k: (s, i, k)),
             pl.BlockSpec((1, bk, bn), lambda s, i, j, k: (s, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda s, i, j, k: (s, i, j)),
         out_shape=jax.ShapeDtypeStruct((S, M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(moduli.reshape(-1, 1), a_res, b_res)
+    )(moduli.reshape(-1), a_res, b_res)

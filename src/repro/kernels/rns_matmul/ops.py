@@ -21,6 +21,16 @@ def _pad_to(x, axis: int, mult: int):
     return jnp.pad(x, widths)
 
 
+def require_int8_residues(t) -> None:
+    """The digit matmuls feed residues to the MXU as int8 operands, which
+    is exact only while every modulus is <= 128."""
+    if not t.profile.int8_safe:
+        raise ValueError(
+            f"profile {t.profile.name} has moduli above 128: its residues "
+            "do not fit the int8 MXU operands of the Pallas kernels; use "
+            "the reference backend")
+
+
 def rns_matmul(
     profile, a_res, b_res, *, bm: int | None = None, bn: int | None = None,
     bk: int | None = None, interpret: bool | None = None,
@@ -39,6 +49,7 @@ def rns_matmul(
     if interpret is None:
         interpret = dispatch.default_interpret()
     t = tables(profile)
+    require_int8_residues(t)
     if bm is None or bn is None or bk is None:
         from repro.kernels import autotune
 

@@ -25,9 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.rns import tables
 
@@ -100,7 +98,7 @@ def rns_normalize_tiles(x, *, profile, bt: int = 1024, interpret: bool = False):
         in_specs=[pl.BlockSpec((K, bt), lambda i: (0, i))],
         out_specs=pl.BlockSpec((bt,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((T,), jnp.float32),
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -10,6 +10,7 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
 #: XLA flag that splits the host CPU into N virtual devices — the only
 #: way to exercise real GSPMD partitioning / shard_map collectives
@@ -30,11 +31,15 @@ def virtual_cpu_env(n: int, base: dict | None = None) -> dict:
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    """A mesh whose axes are all ``Auto``: GSPMD places what the sharding
+    rules leave open (``jax.make_mesh`` defaults to ``Explicit`` axes,
+    under which an unannotated gather such as ``embed``'s ``jnp.take``
+    of a vocab-sharded table is a type error)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_digit_mesh(n_model: int | None = None, *, n_data: int = 1):
@@ -59,4 +64,4 @@ def make_digit_mesh(n_model: int | None = None, *, n_data: int = 1):
             f"have {n_dev} (CPU testing: set XLA_FLAGS="
             f"{VIRTUAL_CPU_FLAG.format(n=n_data * n_model)} before jax "
             "initializes)")
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return make_mesh((n_data, n_model), ("data", "model"))

@@ -37,6 +37,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve.engine import ContinuousEngine, Engine, ServeConfig
 
@@ -199,6 +200,7 @@ def main():
                          "whose digit count divides the device count)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=True)
     if args.rns:
         from repro.core.rns_matmul import RnsDotConfig

@@ -18,6 +18,7 @@ import logging
 from repro.configs.base import get_config
 from repro.core.rns_matmul import RnsDotConfig
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import Trainer, TrainerConfig
 
@@ -36,6 +37,7 @@ def main():
                     help="route MLP matmuls through the RNS datapath")
     ap.add_argument("--rns-profile", default="rns9")
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -697,8 +697,13 @@ class ContinuousEngine:
         if self.cfg.rns is None:
             return dispatch.OpCounts()
         if not self._op_cache:
-            for name, (fn, args) in self._trace_specs().items():
-                self._op_cache[name] = dispatch.trace_op_counts(fn, *args)
+            from repro.distributed.sharding import use_digit_sharding
+
+            # count what the jits trace: under the engine's digit mesh the
+            # composites decompose into the sharded primitives
+            with use_digit_sharding(self.scfg.mesh, self.scfg.digit_axis):
+                for name, (fn, args) in self._trace_specs().items():
+                    self._op_cache[name] = dispatch.trace_op_counts(fn, *args)
         if self.chunked:
             return self._op_cache["mixed"]
         return self._op_cache["decode"].add(self._op_cache["prefill"],

@@ -306,6 +306,18 @@ class TestLintRules:
         assert not lint_source(src, "serve/engine.py")  # host code is fine
         assert lint_source("x = np.random.uniform(0, 1)\n", "core/rns.py")
 
+    @pytest.mark.parametrize("spec, flagged", [
+        ("pl.BlockSpec()", True),
+        ("pl.BlockSpec(None, lambda i: (i,))", True),
+        ("pl.BlockSpec(memory_space=pltpu.VMEM)", True),
+        ("pl.BlockSpec(memory_space=pltpu.SMEM)", False),
+        ("pl.BlockSpec((8, 128), lambda i: (i, 0))", False),
+    ])
+    def test_whole_array_vmem(self, spec, flagged):
+        vs = lint_source(spec + "\n", "kernels/rns_matmul/kernel.py")
+        assert [v.rule for v in vs] == (["whole-array-vmem"] if flagged
+                                        else [])
+
     def test_line_suppression_covers_line_and_next(self):
         src = ("# lint-ok: raw-digits (unit test)\n"
                "y = rt.digits + 1\n"

@@ -34,10 +34,7 @@ class TestDigitShardingRules:
     def _mesh(self, shape=(1, 8), axes=("data", "model")):
         from jax.sharding import AbstractMesh
 
-        try:
-            return AbstractMesh(shape, axes)
-        except TypeError:
-            return AbstractMesh(tuple(zip(axes, shape)))
+        return AbstractMesh(shape, axes)
 
     def test_shards_requires_divisibility(self):
         from repro.distributed.sharding import DigitSharding

@@ -108,9 +108,10 @@ class TestTileContract:
         # streamed, no scratch -> 2 * (36864 + 4096)
         assert vmem_bytes("rns_normalize", {"bt": 1024},
                           n_digits=9) == 2 * (9 * 1024 * 4 + 1024 * 4)
-        # rns_matmul defaults, K=9: moduli + a + b + out tiles double-
-        # buffered, plus the (bm, bn) f32 accumulator scratch once
-        streamed = (1 * 1 + 128 * 512 + 512 * 128 + 128 * 128) * 4
+        # rns_matmul defaults, K=9: a + b + out tiles double-buffered
+        # (the moduli sit whole in SMEM), plus the (bm, bn) f32
+        # accumulator scratch once
+        streamed = (128 * 512 + 512 * 128 + 128 * 128) * 4
         assert vmem_bytes("rns_matmul", {"bm": 128, "bn": 128, "bk": 512},
                           n_digits=9) == 2 * streamed + 128 * 128 * 4
 
@@ -154,6 +155,22 @@ class TestWrapperGuards:
                            match="rns_matmul: illegal block config"):
             rns_matmul("rns9", ra, rb, bn=100)
 
+    @pytest.mark.parametrize("kind", ["rns_matmul", "rns_fused_dot"])
+    def test_refuses_residues_wider_than_int8(self, kind):
+        """The digit dots take int8 MXU operands: a profile with moduli
+        above 128 must be refused, not silently wrapped."""
+        from repro.kernels.rns_fused.ops import rns_fused_dot
+        from repro.kernels.rns_matmul.ops import rns_matmul
+
+        p = get_profile("rns8_u8")
+        assert not p.int8_safe
+        b = jnp.zeros((p.n_digits, 512, 128), jnp.int32)
+        with pytest.raises(ValueError, match="moduli above 128"):
+            if kind == "rns_matmul":
+                rns_matmul(p, jnp.zeros((p.n_digits, 8, 512), jnp.int32), b)
+            else:
+                rns_fused_dot(p, jnp.zeros((8, 512), jnp.float32), 1.0, b)
+
     def test_rns_convert_refuses_illegal_bt(self):
         from repro.kernels.rns_convert.ops import rns_convert
 
@@ -179,6 +196,46 @@ class TestCaptureLayer:
         # the closed-form model must be conservative vs the real launch
         assert launch_vmem_bytes(ln) <= vmem_bytes(
             "rns_matmul", autotune.DEFAULTS["rns_matmul"], n_digits=9)
+
+    @pytest.mark.parametrize("kind", ["rns_matmul",
+                                      "rns_fused_encode_matmul"])
+    def test_capture_places_smem_moduli_whole(self, kind):
+        """The moduli ride as a whole-array SMEM operand: no tiles to
+        check, and no VMEM charged for them."""
+        from repro.kernels.rns_fused.ops import rns_fused_encode_matmul
+        from repro.kernels.rns_matmul.ops import rns_matmul
+
+        b = jax.ShapeDtypeStruct((9, 512, 512), jnp.int32)
+        if kind == "rns_matmul":
+            (ln,) = capture_launches(
+                lambda a, b: rns_matmul("rns9", a, b),
+                jax.ShapeDtypeStruct((9, 8, 512), jnp.int32), b)
+            a_tile = 8 * 512 * 4
+        else:
+            (ln,) = capture_launches(
+                lambda x, b: rns_fused_encode_matmul("rns9", x, 2.0, b),
+                jax.ShapeDtypeStruct((8, 512), jnp.float32), b)
+            a_tile = 8 * 512 * 4 + 8 * 1 * 4      # x tile + scale rows
+        assert ln.kind == kind
+        assert ln.in_specs[0] == (None, None)
+        assert ln.operands[0][0] == (9,)
+        assert check_launch(ln) == []
+        streamed = a_tile + 512 * 128 * 4 + 8 * 128 * 4
+        assert launch_vmem_bytes(ln) == 2 * streamed + 8 * 128 * 4
+
+    def test_unit_sublane_block_over_a_longer_dim_refused(self):
+        """A (1, 1) block over a (K, 1) array is what v5e's compiler
+        refused for the old moduli operand; the auditor had passed it."""
+        from repro.analysis.kernel_audit import KernelLaunch
+
+        ln = KernelLaunch(
+            kind="rns_matmul", kernel_name="_kernel", profile="rns9",
+            grid=(9,), in_specs=(((1, 1), lambda s: (s, 0)),),
+            out_specs=(((1, 128), lambda s: (s, 0)),),
+            operands=(((9, 1), "int32", 4),), outs=(((9, 128), "int32", 4),),
+            scratch=())
+        violations = check_launch(ln)
+        assert any("in0" in v and "sublane" in v for v in violations)
 
     def test_capture_drops_its_poisoned_traces(self):
         from repro.kernels.rns_matmul.kernel import rns_matmul_tiles

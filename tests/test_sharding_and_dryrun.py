@@ -15,10 +15,7 @@ class TestSpecResolution:
         # AbstractMesh: rule resolution only needs axis names + sizes
         from jax.sharding import AbstractMesh
 
-        try:
-            return AbstractMesh(shape, axes)            # jax >= 0.5
-        except TypeError:
-            return AbstractMesh(tuple(zip(axes, shape)))  # jax 0.4.x
+        return AbstractMesh(shape, axes)
 
     def test_basic_rules(self):
         from jax.sharding import PartitionSpec as P
@@ -76,11 +73,12 @@ from repro.configs.base import get_config, ShapeConfig
 from repro.launch import specs as SP
 from repro.launch.dryrun import lower_cell
 from repro.launch.hlo_cost import analyze_hlo
+from repro.launch.mesh import make_mesh
 
 out = {}
 for mesh_shape, axes, tag in [((2, 4), ("data", "model"), "single"),
                               ((2, 2, 4), ("pod", "data", "model"), "multi")]:
-    mesh = jax.make_mesh(mesh_shape, axes)
+    mesh = make_mesh(mesh_shape, axes)
     cfg = SP.with_shape_overrides(get_config("smollm-135m"))
     rec = {}
     for shape in [ShapeConfig("train", 256, 8, "train"),
