@@ -1,0 +1,9 @@
+"""The benchmark's own tests: importable as ``chipbench`` from the root."""
+
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+for p in (str(ROOT), str(ROOT / "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
