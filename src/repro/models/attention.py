@@ -400,18 +400,23 @@ def gqa_decode_packed(p, x, cfg, cache, seg, pos, *, rns=None, use_rope=True):
     from repro.serve.kv_cache import gather_pages, write_packed_tokens
 
     N = x.shape[1]
-    q, k, v = gqa_qkv(p, x, cfg, pos[None], rns, use_rope=use_rope)
-    k_pages = write_packed_tokens(cache["k_pages"], cache["block_table"],
-                                  seg, pos, k[0])
-    v_pages = write_packed_tokens(cache["v_pages"], cache["block_table"],
-                                  seg, pos, v[0])
-    R = cache["block_table"].shape[0]
-    segc = jnp.clip(seg, 0, R - 1)
-    kd = gather_pages(k_pages, cache["block_table"])[segc]   # [N, S, Hk, D]
-    vd = gather_pages(v_pages, cache["block_table"])[segc]
-    out, _lse = decode_attention(q[0][:, None], kd, vd, pos + 1,
-                                 window=cfg.attn_window)
-    y = linear(p["wo"], out.reshape(1, N, -1), rns)
+    with jax.named_scope("qkv"):
+        q, k, v = gqa_qkv(p, x, cfg, pos[None], rns, use_rope=use_rope)
+    with jax.named_scope("kv_write"):
+        k_pages = write_packed_tokens(cache["k_pages"], cache["block_table"],
+                                      seg, pos, k[0])
+        v_pages = write_packed_tokens(cache["v_pages"], cache["block_table"],
+                                      seg, pos, v[0])
+    with jax.named_scope("attn_gather"):
+        R = cache["block_table"].shape[0]
+        segc = jnp.clip(seg, 0, R - 1)
+        kd = gather_pages(k_pages, cache["block_table"])[segc]  # [N,S,Hk,D]
+        vd = gather_pages(v_pages, cache["block_table"])[segc]
+    with jax.named_scope("attn"):
+        out, _lse = decode_attention(q[0][:, None], kd, vd, pos + 1,
+                                     window=cfg.attn_window)
+    with jax.named_scope("attn_out"):
+        y = linear(p["wo"], out.reshape(1, N, -1), rns)
     return y, k_pages, v_pages
 
 
@@ -717,34 +722,41 @@ def mla_decode_packed(p, x, cfg, cache, seg, pos, dec, *, rns=None):
     m = cfg.mla
     N = x.shape[1]
     H = cfg.n_heads
-    q_nope, q_rope, c_kv_t, k_rope_t = _mla_proj_at(p, x, cfg, pos[None],
-                                                    rns)
-    ckv_pages = write_packed_tokens(cache["ckv_pages"], cache["block_table"],
-                                    seg, pos, c_kv_t[0])
-    krope_pages = write_packed_tokens(cache["krope_pages"],
-                                      cache["block_table"],
-                                      seg, pos, k_rope_t[0])
-    R = cache["block_table"].shape[0]
-    segc = jnp.clip(seg, 0, R - 1)
-    c_kv = gather_pages(ckv_pages, cache["block_table"])[segc]      # [N,S,r]
-    k_rope = gather_pages(krope_pages, cache["block_table"])[segc]  # [N,S,dr]
-    qn = q_nope[0][:, None]                                     # [N,1,H,dn]
-    qr = q_rope[0][:, None]
-    # absorbed context: bitwise the solo decode math per row
-    abs_out, _ = _mla_absorbed_ctx(p, cfg, qn, qr, c_kv, k_rope, pos + 1,
-                                   window=cfg.attn_window)
-    # expanded context: bitwise the solo prefill math per chunk token
-    S = c_kv.shape[1]
-    k_nope, v = _multi_proj(c_kv, (p["wuk"], p["wuv"]), rns)
-    k_nope = k_nope.reshape(N, S, H, m.qk_nope_dim)
-    v = v.reshape(N, S, H, m.v_dim)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
-                                  (N, S, H, m.qk_rope_dim))], axis=-1)
-    q = jnp.concatenate([qn, qr], axis=-1)
-    exp_out, _lse = decode_attention(q, k, v, pos + 1,
-                                     window=cfg.attn_window)    # [N,1,H,vd]
-    out = jnp.where(dec[:, None, None, None], abs_out,
-                    exp_out.astype(jnp.float32))
-    y = linear(p["wo"], out.reshape(1, N, -1).astype(x.dtype), rns)
+    with jax.named_scope("qkv"):
+        q_nope, q_rope, c_kv_t, k_rope_t = _mla_proj_at(p, x, cfg, pos[None],
+                                                        rns)
+    with jax.named_scope("kv_write"):
+        ckv_pages = write_packed_tokens(cache["ckv_pages"],
+                                        cache["block_table"],
+                                        seg, pos, c_kv_t[0])
+        krope_pages = write_packed_tokens(cache["krope_pages"],
+                                          cache["block_table"],
+                                          seg, pos, k_rope_t[0])
+    with jax.named_scope("attn_gather"):
+        R = cache["block_table"].shape[0]
+        segc = jnp.clip(seg, 0, R - 1)
+        c_kv = gather_pages(ckv_pages, cache["block_table"])[segc]  # [N,S,r]
+        k_rope = gather_pages(krope_pages,
+                              cache["block_table"])[segc]           # [N,S,dr]
+    with jax.named_scope("attn"):
+        qn = q_nope[0][:, None]                                 # [N,1,H,dn]
+        qr = q_rope[0][:, None]
+        # absorbed context: bitwise the solo decode math per row
+        abs_out, _ = _mla_absorbed_ctx(p, cfg, qn, qr, c_kv, k_rope, pos + 1,
+                                       window=cfg.attn_window)
+        # expanded context: bitwise the solo prefill math per chunk token
+        S = c_kv.shape[1]
+        k_nope, v = _multi_proj(c_kv, (p["wuk"], p["wuv"]), rns)
+        k_nope = k_nope.reshape(N, S, H, m.qk_nope_dim)
+        v = v.reshape(N, S, H, m.v_dim)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                      (N, S, H, m.qk_rope_dim))], axis=-1)
+        q = jnp.concatenate([qn, qr], axis=-1)
+        exp_out, _lse = decode_attention(q, k, v, pos + 1,
+                                         window=cfg.attn_window)  # [N,1,H,vd]
+        out = jnp.where(dec[:, None, None, None], abs_out,
+                        exp_out.astype(jnp.float32))
+    with jax.named_scope("attn_out"):
+        y = linear(p["wo"], out.reshape(1, N, -1).astype(x.dtype), rns)
     return y, ckv_pages, krope_pages

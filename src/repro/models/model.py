@@ -403,6 +403,11 @@ def mixed_step(params, cfg, tokens, seg, pos, dec, valid, cache):
     ``lengths`` are not advanced; the engine owns them host-side and
     pushes fresh tables before every step.
 
+    Every op of the step carries one of the ``jax.named_scope`` names
+    ``embed``, ``norm``, ``qkv``, ``kv_write``, ``attn_gather``, ``attn``,
+    ``attn_out``, ``mlp`` or ``logits`` in its HLO ``op_name``; ops under
+    none of them are the layer scan's own (carry copies, weight slices).
+
     Token identity: per-token quantization grids (see
     :func:`prefill_ragged`), write-then-gather packed attention
     (models/attention.py ``*_decode_packed``), and a float32 page pool
@@ -412,14 +417,16 @@ def mixed_step(params, cfg, tokens, seg, pos, dec, valid, cache):
 
     mask = valid[None] if cfg.rns is not None else None
     with token_mask(mask, per_token=True):
-        h = _embed_tokens(params, cfg, tokens[None])
-        if cfg.pos_emb == "sinusoidal":
-            table = sinusoidal_positions(_cache_smax(cfg, cache), cfg.d_model,
-                                         h.dtype)
-            h = h + table[pos][None]
+        with jax.named_scope("embed"):
+            h = _embed_tokens(params, cfg, tokens[None])
+            if cfg.pos_emb == "sinusoidal":
+                table = sinusoidal_positions(_cache_smax(cfg, cache),
+                                             cfg.d_model, h.dtype)
+                h = h + table[pos][None]
         h, ys, _ = tf.apply_blocks(params["blocks"], h, cfg, mode="decode",
                                    cache=cache, packed=(seg, pos, dec))
-        logits = _logits(params, cfg, h)[0]
+        with jax.named_scope("logits"):
+            logits = _logits(params, cfg, h)[0]
     return logits, ys
 
 

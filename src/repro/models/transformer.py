@@ -138,7 +138,8 @@ def _apply_layer(lp, h, cfg, layer_type, mlp_type, *, mode, positions,
     use_rope = cfg.pos_emb == "rope"
 
     if layer_type in ("attn", "mla"):
-        hn = norm(lp["ln1"], h, cfg.norm)
+        with jax.named_scope("norm"):
+            hn = norm(lp["ln1"], h, cfg.norm)
         if mode == "decode":
             # paged caches (continuous batching) are recognized by their
             # page-pool keys; the dense layout stays the default.  A
@@ -200,7 +201,8 @@ def _apply_layer(lp, h, cfg, layer_type, mlp_type, *, mode, positions,
                     lp["attn"], hn, cfg, mode=amode, positions=positions,
                     kv_mask=kv_mask, rns=rns_a, chunk=chunk)
             prefill_kv = kv
-        h = h + y
+        with jax.named_scope("attn_out"):
+            h = h + y
         if "xattn" in lp:  # enc-dec decoder cross-attention
             hx = norm(lp["lnx"], h, cfg.norm)
             if mode == "decode":
@@ -235,8 +237,11 @@ def _apply_layer(lp, h, cfg, layer_type, mlp_type, *, mode, positions,
         h = h + y
 
     if mlp_type in ("dense", "__enc__"):
-        hn = norm(lp["ln2"], h, cfg.norm)
-        h = h + mlp(lp["mlp"], hn, gated=cfg.gated_mlp, act=cfg.act, rns=rns_m)
+        with jax.named_scope("norm"):
+            hn = norm(lp["ln2"], h, cfg.norm)
+        with jax.named_scope("mlp"):
+            h = h + mlp(lp["mlp"], hn, gated=cfg.gated_mlp, act=cfg.act,
+                        rns=rns_m)
     elif mlp_type == "moe":
         hn = norm(lp["ln2"], h, cfg.norm)
         y, aux = moe_lib.moe_ffn(lp["moe"], hn, cfg.moe, act=cfg.act, rns=rns_m)

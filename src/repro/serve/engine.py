@@ -48,6 +48,7 @@ from repro.core import dispatch
 from repro.models import model as M
 from repro.serve import kv_cache as kv
 from repro.serve.scheduler import Request, Scheduler
+from repro.serve.trace import Tracer
 
 
 @dataclasses.dataclass
@@ -476,6 +477,9 @@ class ContinuousEngine:
         self.latencies: dict[int, float] = {}    # submit -> finish, seconds
         self.ttfts: dict[int, float] = {}        # submit -> first token
         self._op_cache: dict[str, dispatch.OpCounts] = {}
+        # in-program spans and request events (serve/trace.py); off
+        # unless a caller swaps in ``Tracer(on=True)``
+        self.tracer = Tracer()
         self.audit_report = _maybe_audit(self)
 
     # ----------------------------------------------------------- ingest ---
@@ -534,7 +538,8 @@ class ContinuousEngine:
         """
         logits, cache = M.mixed_step(params, self.cfg, tokens, seg, pos,
                                      dec, valid, cache)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+        with jax.named_scope("logits"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     def _cow_fn(self, cache, src, dst):
         """Copy-on-write page duplication across every layer's pool."""
@@ -607,8 +612,10 @@ class ContinuousEngine:
                 "raise ServeConfig.prompt_pad or turn on chunked_prefill")
         rid = self._next_rid
         self._next_rid += 1
+        t = time.perf_counter()
         self.sched.submit(Request(rid=rid, tokens=prompt, max_new=max_new,
-                                  submit_time=time.perf_counter()))
+                                  submit_time=t))
+        self.tracer.event(rid, "submit", t)
         return rid
 
     # ----------------------------------------------------------- stepping --
@@ -627,14 +634,18 @@ class ContinuousEngine:
         self.sched.register_prefix(seq)
         seq.emitted = [tok0]
         seq.last_token = tok0
-        ttft = time.perf_counter() - seq.req.submit_time
+        now = time.perf_counter()
+        ttft = now - seq.req.submit_time
         self.ttfts[seq.rid] = ttft
         self._step_ttfts.append(ttft)
+        self.tracer.event(seq.rid, "first_token", now)
         # length stays at T: the decode step writes tok0's KV at position T
 
     def _finish(self, seq):
         self.results[seq.rid] = np.asarray(seq.emitted, np.int32)
-        self.latencies[seq.rid] = time.perf_counter() - seq.req.submit_time
+        now = time.perf_counter()
+        self.latencies[seq.rid] = now - seq.req.submit_time
+        self.tracer.event(seq.rid, "finish", now)
         self.sched.complete(seq)
         self._tables_dirty = True
 
@@ -711,22 +722,27 @@ class ContinuousEngine:
 
     def _decode_vanilla(self, last):
         """One [R, 1] decode for every running row; returns #new tokens."""
-        nxt, self.cache = self._decode(
-            self.params, jnp.asarray(last[:, None]), self.cache,
-            jnp.asarray(self._active))
-        nxt = np.asarray(nxt, np.int32)
+        tr = self.tracer
+        with tr.span("upload"):
+            args = (jnp.asarray(last[:, None]), jnp.asarray(self._active))
+        with tr.span("dispatch"):
+            nxt, self.cache = self._decode(self.params, args[0], self.cache,
+                                           args[1])
+        with tr.span("readback"):
+            nxt = np.asarray(nxt, np.int32)
         n_tokens = 0
-        for seq in list(self.sched.running.values()):
-            tok = int(nxt[seq.slot])
-            seq.emitted.append(tok)
-            seq.last_token = tok
-            seq.length += 1
-            n_tokens += 1
-            if (len(seq.emitted) >= seq.req.max_new
-                    or tok == self.scfg.eos_id
-                    or seq.length + 1 > self.pcfg.tokens_per_seq):
-                self._step_finished.append(seq.rid)
-                self._finish(seq)
+        with tr.span("emit"):
+            for seq in list(self.sched.running.values()):
+                tok = int(nxt[seq.slot])
+                seq.emitted.append(tok)
+                seq.last_token = tok
+                seq.length += 1
+                n_tokens += 1
+                if (len(seq.emitted) >= seq.req.max_new
+                        or tok == self.scfg.eos_id
+                        or seq.length + 1 > self.pcfg.tokens_per_seq):
+                    self._step_finished.append(seq.rid)
+                    self._finish(seq)
         return n_tokens
 
     def _decode_spec(self, last):
@@ -736,38 +752,46 @@ class ContinuousEngine:
         plus the bonus greedy token after it) — between 1 and W per step,
         token-identical to vanilla decode by the greedy accept rule.
         """
+        tr = self.tracer
         R, W, bs = self.pcfg.max_seqs, self.spec_window, self.pcfg.page_size
-        window = np.zeros((R, W), np.int32)
-        caps = np.zeros((R,), np.int32)
-        for seq in self.sched.running.values():
-            window[seq.slot, 0] = seq.last_token
-            window[seq.slot, 1:] = self._propose(seq)
-            remaining = seq.req.max_new - len(seq.emitted)
-            caps[seq.slot] = max(0, min(
-                W - 1,
-                remaining - 1,                       # a+1 <= max_new budget
-                len(seq.pages) * bs - seq.length - 1))   # KV on real pages
-        g, a, self.cache = self._verify(
-            self.params, jnp.asarray(window), self.cache,
-            jnp.asarray(self._active), jnp.asarray(caps))
-        g, a = np.asarray(g, np.int32), np.asarray(a, np.int32)
+        with tr.span("pack"):
+            window = np.zeros((R, W), np.int32)
+            caps = np.zeros((R,), np.int32)
+            for seq in self.sched.running.values():
+                window[seq.slot, 0] = seq.last_token
+                window[seq.slot, 1:] = self._propose(seq)
+                remaining = seq.req.max_new - len(seq.emitted)
+                caps[seq.slot] = max(0, min(
+                    W - 1,
+                    remaining - 1,                   # a+1 <= max_new budget
+                    len(seq.pages) * bs - seq.length - 1))  # KV on real pages
+        with tr.span("upload"):
+            args = (jnp.asarray(window), jnp.asarray(self._active),
+                    jnp.asarray(caps))
+        with tr.span("dispatch"):
+            g, a, self.cache = self._verify(self.params, args[0], self.cache,
+                                            *args[1:])
+        with tr.span("readback"):
+            g, a = np.asarray(g, np.int32), np.asarray(a, np.int32)
         n_tokens = 0
-        for seq in list(self.sched.running.values()):
-            ar = int(a[seq.slot])
-            toks = list(window[seq.slot, 1:ar + 1]) + [int(g[seq.slot, ar])]
-            if self.scfg.eos_id >= 0 and self.scfg.eos_id in toks:
-                toks = toks[: toks.index(self.scfg.eos_id) + 1]
-            seq.emitted.extend(int(t) for t in toks)
-            seq.last_token = seq.emitted[-1]
-            seq.length += ar + 1        # matches the device-side bump
-            n_tokens += len(toks)
-            self._spec_accepted += ar
-            self._spec_proposed += int(caps[seq.slot]) if W > 1 else 0
-            if (len(seq.emitted) >= seq.req.max_new
-                    or seq.emitted[-1] == self.scfg.eos_id
-                    or seq.length + 1 > self.pcfg.tokens_per_seq):
-                self._step_finished.append(seq.rid)
-                self._finish(seq)
+        with tr.span("emit"):
+            for seq in list(self.sched.running.values()):
+                ar = int(a[seq.slot])
+                toks = (list(window[seq.slot, 1:ar + 1])
+                        + [int(g[seq.slot, ar])])
+                if self.scfg.eos_id >= 0 and self.scfg.eos_id in toks:
+                    toks = toks[: toks.index(self.scfg.eos_id) + 1]
+                seq.emitted.extend(int(t) for t in toks)
+                seq.last_token = seq.emitted[-1]
+                seq.length += ar + 1        # matches the device-side bump
+                n_tokens += len(toks)
+                self._spec_accepted += ar
+                self._spec_proposed += int(caps[seq.slot]) if W > 1 else 0
+                if (len(seq.emitted) >= seq.req.max_new
+                        or seq.emitted[-1] == self.scfg.eos_id
+                        or seq.length + 1 > self.pcfg.tokens_per_seq):
+                    self._step_finished.append(seq.rid)
+                    self._finish(seq)
         return n_tokens
 
     def _step_mixed(self) -> dict:
@@ -781,16 +805,77 @@ class ContinuousEngine:
         the tail lane of a prompt's last chunk yields its first token
         (TTFT), decode windows go through the same greedy accept rule as
         the batched verify step.
+
+        Under the tracer, the ``step`` span's children are ``schedule``,
+        ``cow``, ``plan``, ``pack``, ``set_tables``, ``upload``,
+        ``dispatch``, ``readback``, ``emit`` and ``stats``, in that order.
         """
-        t0 = time.perf_counter()
+        tr = self.tracer
         self._step_finished: list[int] = []
         self._step_ttfts: list[float] = []
         self._spec_accepted = self._spec_proposed = 0
-        plan = self.sched.schedule()
+        plan = self._schedule()
         if plan.cow:
             # duplicate shared pages BEFORE any packed write lands on them
-            self._apply_cow(plan.cow)
-        segs = self.sched.plan_mixed(self.spec_window)
+            with tr.span("cow"):
+                self._apply_cow(plan.cow)
+        with tr.span("plan"):
+            segs = self.sched.plan_mixed(self.spec_window)
+        with tr.span("pack"):
+            lanes, caps, prefill_tokens, decode_lanes = self._pack(segs)
+        n_tokens = 0
+        if segs:
+            n_tokens = self._run_mixed(segs, lanes, caps)
+        elif self.sched.running:
+            raise RuntimeError("mixed step planned no segments while rows "
+                               "are running — scheduler liveness bug")
+        with tr.span("stats"):
+            self._step_idx += 1
+            alloc = self.sched.alloc
+            W = self.spec_window
+            return {
+                "step": self._step_idx,
+                "admitted": [s.rid for s in plan.admitted],
+                "preempted": plan.preempted,
+                "finished": self._step_finished,
+                "active": len(self.sched.running),
+                "waiting": len(self.sched.waiting),
+                "new_tokens": n_tokens,
+                "decoded": decode_lanes > 0,
+                "decode_rows": decode_lanes // W,
+                "page_utilization": alloc.utilization,
+                "cow_splits": len(plan.cow),
+                "cache_hit_tokens": sum(s.cached_tokens
+                                        for s in plan.admitted),
+                "pages_allocated_total": alloc.pages_allocated,
+                "pages_shared_total": alloc.pages_shared,
+                "pages_window_evicted": self.sched.window_evictions,
+                "spec_proposed": self._spec_proposed,
+                "spec_accepted": self._spec_accepted,
+                "rns_ops": self._rns_ops(0),
+                # phase mix of this packed step + first-token latency
+                "prefill_tokens": prefill_tokens,
+                "decode_tokens": n_tokens,
+                "ttft_ms": (1e3 * float(np.mean(self._step_ttfts))
+                            if self._step_ttfts else 0.0),
+            }
+
+    def _schedule(self):
+        """``sched.schedule()`` under the ``schedule`` span, then the
+        ``admit`` and ``preempt`` request events of its plan."""
+        with self.tracer.span("schedule"):
+            plan = self.sched.schedule()
+        for seq in plan.admitted:
+            self.tracer.event(seq.rid, "admit")
+        for rid in plan.preempted:
+            self.tracer.event(rid, "preempt")
+        return plan
+
+    def _pack(self, segs):
+        """Fill the [token_budget] lanes from the planned segments.
+
+        Returns ((tokens, slots, positions, is_decode, is_valid), accept
+        caps by slot, prefill tokens, decode lanes)."""
         N, W, bs = self.scfg.token_budget, self.spec_window, self.pcfg.page_size
         tok = np.zeros((N,), np.int32)
         sg = np.full((N,), -1, np.int32)
@@ -820,8 +905,14 @@ class ContinuousEngine:
                 tok[off:off + n] = s.tokens
                 prefill_tokens += n
             off += n
-        n_tokens = 0
-        if segs:
+        return (tok, sg, ps, dc, vd), caps, prefill_tokens, decode_lanes
+
+    def _run_mixed(self, segs, lanes, caps) -> int:
+        """Upload the tables and lanes, run ``self._mixed`` once, read the
+        per-lane argmaxes back and emit; returns the tokens emitted."""
+        tr = self.tracer
+        W = self.spec_window
+        with tr.span("set_tables"):
             # tables go up every step: block tables shift under admission
             # / growth / COW, and the packed step reads positions directly
             # (cache lengths are advanced host-side only)
@@ -829,11 +920,15 @@ class ContinuousEngine:
             self.cache = kv.set_tables(self.cache, bt, lengths)
             self._active = active
             self._tables_dirty = False
-            g, self.cache = self._mixed(
-                self.params, jnp.asarray(tok), jnp.asarray(sg),
-                jnp.asarray(ps), jnp.asarray(dc), jnp.asarray(vd),
-                self.cache)
+        with tr.span("upload"):
+            args = [jnp.asarray(a) for a in lanes]
+        with tr.span("dispatch"):
+            g, self.cache = self._mixed(self.params, *args, self.cache)
+        with tr.span("readback"):
             g = np.asarray(g, np.int32)
+        tok = lanes[0]
+        n_tokens = 0
+        with tr.span("emit"):
             now = time.perf_counter()
             for s in segs:
                 seq = s.seq
@@ -845,6 +940,7 @@ class ContinuousEngine:
                         ttft = now - seq.req.submit_time
                         self.ttfts[seq.rid] = ttft
                         self._step_ttfts.append(ttft)
+                        tr.event(seq.rid, "first_token", now)
                         n_tokens += 1
                     # full blocks become prefix-cache hits as they land,
                     # not only once the whole prompt is in
@@ -876,37 +972,7 @@ class ContinuousEngine:
                             or seq.length + 1 > self.pcfg.tokens_per_seq):
                         self._step_finished.append(seq.rid)
                         self._finish(seq)
-        elif self.sched.running:
-            raise RuntimeError("mixed step planned no segments while rows "
-                               "are running — scheduler liveness bug")
-        self._step_idx += 1
-        alloc = self.sched.alloc
-        return {
-            "step": self._step_idx,
-            "admitted": [s.rid for s in plan.admitted],
-            "preempted": plan.preempted,
-            "finished": self._step_finished,
-            "active": len(self.sched.running),
-            "waiting": len(self.sched.waiting),
-            "new_tokens": n_tokens,
-            "decoded": decode_lanes > 0,
-            "decode_rows": decode_lanes // W,
-            "page_utilization": alloc.utilization,
-            "cow_splits": len(plan.cow),
-            "cache_hit_tokens": sum(s.cached_tokens for s in plan.admitted),
-            "pages_allocated_total": alloc.pages_allocated,
-            "pages_shared_total": alloc.pages_shared,
-            "pages_window_evicted": self.sched.window_evictions,
-            "spec_proposed": self._spec_proposed,
-            "spec_accepted": self._spec_accepted,
-            "rns_ops": self._rns_ops(0),
-            # phase mix of this packed step + first-token latency
-            "prefill_tokens": prefill_tokens,
-            "decode_tokens": n_tokens,
-            "ttft_ms": (1e3 * float(np.mean(self._step_ttfts))
-                        if self._step_ttfts else 0.0),
-            "step_time_s": time.perf_counter() - t0,
-        }
+        return n_tokens
 
     def step(self) -> dict:
         """One scheduler step: admit/evict, prefill admits, COW-split
@@ -918,22 +984,30 @@ class ContinuousEngine:
 
         With ``chunked_prefill`` on, this dispatches to the packed
         mixed-phase step instead (same stats contract, plus chunked
-        admission semantics).
+        admission semantics).  Either way the step runs under the
+        tracer's ``step`` span.
         """
-        if self.chunked:
-            return self._step_mixed()
-        t0 = time.perf_counter()
+        with self.tracer.span("step", self._step_idx + 1):
+            if self.chunked:
+                return self._step_mixed()
+            return self._step_phased()
+
+    def _step_phased(self) -> dict:
+        """The phase-barrier step: whole-prompt prefill of each admission,
+        then one [R, 1] decode (or [R, W] verify) over the running rows."""
+        tr = self.tracer
         self._step_finished: list[int] = []
         self._step_ttfts: list[float] = []
         self._spec_accepted = self._spec_proposed = 0
-        plan = self.sched.schedule()
+        plan = self._schedule()
         if plan.admitted or plan.preempted or plan.grew or plan.cow:
             self._tables_dirty = True
         for seq in plan.admitted:
             self._do_prefill(seq)
         if plan.cow:
             # duplicate shared pages BEFORE any decode write lands on them
-            self._apply_cow(plan.cow)
+            with tr.span("cow"):
+                self._apply_cow(plan.cow)
         # admission already produced one token per new row: those rows may
         # already be done (max_new=1 or eos on the first token)
         for seq in list(self.sched.running.values()):
@@ -947,49 +1021,55 @@ class ContinuousEngine:
         decoded = bool(self.sched.running)
         decode_rows = len(self.sched.running)
         if self.sched.running:
-            bt, lengths, active, last = self.sched.tables()
-            if self._tables_dirty or not np.array_equal(active, self._active):
-                # topology changed: push fresh tables/lengths; otherwise the
-                # decode step's own active-masked length bump already matches
-                # the host counters and the upload is skipped
-                self.cache = kv.set_tables(self.cache, bt, lengths)
-                self._active = active
-                self._tables_dirty = False
+            with tr.span("set_tables"):
+                bt, lengths, active, last = self.sched.tables()
+                if self._tables_dirty or not np.array_equal(active,
+                                                            self._active):
+                    # topology changed: push fresh tables/lengths;
+                    # otherwise the decode step's own active-masked length
+                    # bump already matches the host counters and the
+                    # upload is skipped
+                    self.cache = kv.set_tables(self.cache, bt, lengths)
+                    self._active = active
+                    self._tables_dirty = False
             if self.scfg.spec_decode:
                 n_tokens = self._decode_spec(last)
             else:
                 n_tokens = self._decode_vanilla(last)
-        self._step_idx += 1
-        alloc = self.sched.alloc
-        return {
-            "step": self._step_idx,
-            "admitted": [s.rid for s in plan.admitted],
-            "preempted": plan.preempted,
-            "finished": self._step_finished,
-            "active": len(self.sched.running),
-            "waiting": len(self.sched.waiting),
-            "new_tokens": n_tokens,
-            "decoded": decoded,
-            "decode_rows": decode_rows,
-            "page_utilization": alloc.utilization,
-            # prefix-cache accounting (cumulative counters + this plan)
-            "cow_splits": len(plan.cow),
-            "cache_hit_tokens": sum(s.cached_tokens for s in plan.admitted),
-            "pages_allocated_total": alloc.pages_allocated,
-            "pages_shared_total": alloc.pages_shared,
-            "pages_window_evicted": self.sched.window_evictions,
-            # speculative accounting (this step)
-            "spec_proposed": self._spec_proposed,
-            "spec_accepted": self._spec_accepted,
-            "rns_ops": self._rns_ops(len(plan.admitted)),
-            # phase accounting (whole-prompt prefill counts padded work
-            # at admission; decode tokens are this step's emissions)
-            "prefill_tokens": sum(len(s.req.tokens) for s in plan.admitted),
-            "decode_tokens": n_tokens,
-            "ttft_ms": (1e3 * float(np.mean(self._step_ttfts))
-                        if self._step_ttfts else 0.0),
-            "step_time_s": time.perf_counter() - t0,
-        }
+        with tr.span("stats"):
+            self._step_idx += 1
+            alloc = self.sched.alloc
+            return {
+                "step": self._step_idx,
+                "admitted": [s.rid for s in plan.admitted],
+                "preempted": plan.preempted,
+                "finished": self._step_finished,
+                "active": len(self.sched.running),
+                "waiting": len(self.sched.waiting),
+                "new_tokens": n_tokens,
+                "decoded": decoded,
+                "decode_rows": decode_rows,
+                "page_utilization": alloc.utilization,
+                # prefix-cache accounting (cumulative counters + this plan)
+                "cow_splits": len(plan.cow),
+                "cache_hit_tokens": sum(s.cached_tokens
+                                        for s in plan.admitted),
+                "pages_allocated_total": alloc.pages_allocated,
+                "pages_shared_total": alloc.pages_shared,
+                "pages_window_evicted": self.sched.window_evictions,
+                # speculative accounting (this step)
+                "spec_proposed": self._spec_proposed,
+                "spec_accepted": self._spec_accepted,
+                "rns_ops": self._rns_ops(len(plan.admitted)),
+                # phase accounting (whole-prompt prefill counts padded
+                # work at admission; decode tokens are this step's
+                # emissions)
+                "prefill_tokens": sum(len(s.req.tokens)
+                                      for s in plan.admitted),
+                "decode_tokens": n_tokens,
+                "ttft_ms": (1e3 * float(np.mean(self._step_ttfts))
+                            if self._step_ttfts else 0.0),
+            }
 
     def run(self, prompts=None, max_new: int | None = None):
         """Serve until drained.  Returns (results {rid: tokens}, stats).
