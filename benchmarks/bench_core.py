@@ -263,12 +263,13 @@ def bench_paged_gather(report):
     rng = np.random.default_rng(5)
     R, nb, bs, Hk, D = 8, 16, 16, 4, 64
     P = 1 + R * nb
-    pages = jnp.asarray(rng.standard_normal((P, bs, Hk, D)), jnp.float32)
+    pages = jnp.asarray(rng.standard_normal((1, P, bs * Hk * D)),
+                        jnp.float32)
     bt = jnp.asarray(
         rng.permutation(np.arange(1, P))[: R * nb].reshape(R, nb), jnp.int32)
     dense = jnp.asarray(rng.standard_normal((R, nb * bs, Hk, D)), jnp.float32)
-    t_gather = _t(jax.jit(lambda p, b: gather_pages(p, b) * 1.0), pages, bt,
-                  n=5)
+    t_gather = _t(jax.jit(lambda p, b: gather_pages(p, 0, b, (Hk, D)) * 1.0),
+                  pages, bt, n=5)
     t_dense = _t(jax.jit(lambda d: d * 1.0), dense, n=5)
     report("paged_gather_8x256", t_gather,
            f"dense_read={t_dense:.0f}us pages={P} page_size={bs} "

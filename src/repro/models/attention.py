@@ -319,12 +319,14 @@ def gqa_decode(p, x, cfg, cache, *, rns=None, use_rope=True):
 def gqa_decode_paged(p, x, cfg, cache, *, rns=None, use_rope=True):
     """One-token decode against a paged KV cache (continuous batching).
 
-    cache: {"k_pages","v_pages" [P,bs,Hk,D], "block_table" [R,nb],
+    cache: {"k_pages","v_pages" the whole stacked pool [n_periods, P,
+    bs*Hk*D], "layer" this layer's period index, "block_table" [R,nb],
     "lengths" [R]}.  The new token's K/V are scattered into the row's
-    current page, then the row's pages are gathered back into a dense
-    [R, nb*bs, Hk, D] view — numerically identical to the dense-cache
-    path (positions past ``lengths`` are masked to exact zeros in the
-    softmax, so the page-pool garbage there never contributes).
+    current page of ``layer`` in place, then the row's pages are
+    gathered back into a dense [R, nb*bs, Hk, D] view — numerically
+    identical to the dense-cache path (positions past ``lengths`` are
+    masked to exact zeros in the softmax, so the page-pool garbage there
+    never contributes).
 
     Returns (y [B,1,d], k_pages, v_pages).
     """
@@ -333,12 +335,13 @@ def gqa_decode_paged(p, x, cfg, cache, *, rns=None, use_rope=True):
     B = x.shape[0]
     positions = cache["lengths"][:, None]
     q, k, v = gqa_qkv(p, x, cfg, positions, rns, use_rope=use_rope)
-    k_pages = write_token(cache["k_pages"], cache["block_table"],
-                          cache["lengths"], k[:, 0])
-    v_pages = write_token(cache["v_pages"], cache["block_table"],
-                          cache["lengths"], v[:, 0])
-    kd = gather_pages(k_pages, cache["block_table"])
-    vd = gather_pages(v_pages, cache["block_table"])
+    layer, bt = cache["layer"], cache["block_table"]
+    k_pages = write_token(cache["k_pages"], layer, bt, cache["lengths"],
+                          k[:, 0])
+    v_pages = write_token(cache["v_pages"], layer, bt, cache["lengths"],
+                          v[:, 0])
+    kd = gather_pages(k_pages, layer, bt, k.shape[2:])
+    vd = gather_pages(v_pages, layer, bt, v.shape[2:])
     out, _lse = decode_attention(q, kd, vd, cache["lengths"] + 1,
                                  window=cfg.attn_window)
     y = linear(p["wo"], out.reshape(B, 1, -1), rns)
@@ -363,12 +366,13 @@ def gqa_decode_paged_window(p, x, cfg, cache, *, rns=None, use_rope=True):
     B, W = x.shape[:2]
     positions = cache["lengths"][:, None] + jnp.arange(W)[None]
     q, k, v = gqa_qkv(p, x, cfg, positions, rns, use_rope=use_rope)
-    k_pages = write_token_window(cache["k_pages"], cache["block_table"],
+    layer, bt = cache["layer"], cache["block_table"]
+    k_pages = write_token_window(cache["k_pages"], layer, bt,
                                  cache["lengths"], k)
-    v_pages = write_token_window(cache["v_pages"], cache["block_table"],
+    v_pages = write_token_window(cache["v_pages"], layer, bt,
                                  cache["lengths"], v)
-    kd = gather_pages(k_pages, cache["block_table"])
-    vd = gather_pages(v_pages, cache["block_table"])
+    kd = gather_pages(k_pages, layer, bt, k.shape[2:])
+    vd = gather_pages(v_pages, layer, bt, v.shape[2:])
     qlen = cache["lengths"][:, None] + 1 + jnp.arange(W)[None]   # [R, W]
     out, _lse = decode_attention(q, kd, vd, qlen, window=cfg.attn_window)
     y = linear(p["wo"], out.reshape(B, W, -1), rns)
@@ -402,16 +406,16 @@ def gqa_decode_packed(p, x, cfg, cache, seg, pos, *, rns=None, use_rope=True):
     N = x.shape[1]
     with jax.named_scope("qkv"):
         q, k, v = gqa_qkv(p, x, cfg, pos[None], rns, use_rope=use_rope)
+    layer, bt = cache["layer"], cache["block_table"]
     with jax.named_scope("kv_write"):
-        k_pages = write_packed_tokens(cache["k_pages"], cache["block_table"],
-                                      seg, pos, k[0])
-        v_pages = write_packed_tokens(cache["v_pages"], cache["block_table"],
-                                      seg, pos, v[0])
+        k_pages = write_packed_tokens(cache["k_pages"], layer, bt, seg, pos,
+                                      k[0])
+        v_pages = write_packed_tokens(cache["v_pages"], layer, bt, seg, pos,
+                                      v[0])
     with jax.named_scope("attn_gather"):
-        R = cache["block_table"].shape[0]
-        segc = jnp.clip(seg, 0, R - 1)
-        kd = gather_pages(k_pages, cache["block_table"])[segc]  # [N,S,Hk,D]
-        vd = gather_pages(v_pages, cache["block_table"])[segc]
+        segc = jnp.clip(seg, 0, bt.shape[0] - 1)
+        kd = gather_pages(k_pages, layer, bt, k.shape[2:])[segc]  # [N,S,Hk,D]
+        vd = gather_pages(v_pages, layer, bt, v.shape[2:])[segc]
     with jax.named_scope("attn"):
         out, _lse = decode_attention(q[0][:, None], kd, vd, pos + 1,
                                      window=cfg.attn_window)
@@ -648,19 +652,22 @@ def mla_decode(p, x, cfg, cache, *, rns=None):
 def mla_decode_paged(p, x, cfg, cache, *, rns=None):
     """MLA decode against a paged latent cache (continuous batching).
 
-    cache: {"ckv_pages" [P,bs,r], "krope_pages" [P,bs,dr], "block_table"
-    [R,nb], "lengths" [R]}.  Returns (y, ckv_pages, krope_pages).
+    cache: {"ckv_pages" [n_periods,P,bs*r], "krope_pages" [n_periods,P,
+    bs*dr] (the whole stacked pools), "layer", "block_table" [R,nb],
+    "lengths" [R]}, as for :func:`gqa_decode_paged`.  Returns (y,
+    ckv_pages, krope_pages).
     """
     from repro.serve.kv_cache import gather_pages, write_token
 
     q_nope, q_rope, c_kv_t, k_rope_t = _mla_decode_proj(
         p, x, cfg, cache["lengths"], rns)
-    ckv_pages = write_token(cache["ckv_pages"], cache["block_table"],
-                            cache["lengths"], c_kv_t[:, 0])
-    krope_pages = write_token(cache["krope_pages"], cache["block_table"],
+    layer, bt = cache["layer"], cache["block_table"]
+    ckv_pages = write_token(cache["ckv_pages"], layer, bt, cache["lengths"],
+                            c_kv_t[:, 0])
+    krope_pages = write_token(cache["krope_pages"], layer, bt,
                               cache["lengths"], k_rope_t[:, 0])
-    c_kv = gather_pages(ckv_pages, cache["block_table"])
-    k_rope = gather_pages(krope_pages, cache["block_table"])
+    c_kv = gather_pages(ckv_pages, layer, bt, c_kv_t.shape[2:])
+    k_rope = gather_pages(krope_pages, layer, bt, k_rope_t.shape[2:])
     y, _lse = _mla_absorbed_attend(
         p, x, cfg, q_nope, q_rope, c_kv, k_rope, cache["lengths"] + 1, rns)
     return y, ckv_pages, krope_pages
@@ -682,13 +689,13 @@ def mla_decode_paged_window(p, x, cfg, cache, *, rns=None):
     W = x.shape[1]
     q_nope, q_rope, c_kv_t, k_rope_t = _mla_decode_proj(
         p, x, cfg, cache["lengths"], rns)
-    ckv_pages = write_token_window(cache["ckv_pages"], cache["block_table"],
+    layer, bt = cache["layer"], cache["block_table"]
+    ckv_pages = write_token_window(cache["ckv_pages"], layer, bt,
                                    cache["lengths"], c_kv_t)
-    krope_pages = write_token_window(cache["krope_pages"],
-                                     cache["block_table"],
+    krope_pages = write_token_window(cache["krope_pages"], layer, bt,
                                      cache["lengths"], k_rope_t)
-    c_kv = gather_pages(ckv_pages, cache["block_table"])
-    k_rope = gather_pages(krope_pages, cache["block_table"])
+    c_kv = gather_pages(ckv_pages, layer, bt, c_kv_t.shape[2:])
+    k_rope = gather_pages(krope_pages, layer, bt, k_rope_t.shape[2:])
     qlen = cache["lengths"][:, None] + 1 + jnp.arange(W)[None]   # [R, W]
     y, _lse = _mla_absorbed_attend(
         p, x, cfg, q_nope, q_rope, c_kv, k_rope, qlen, rns)
@@ -725,19 +732,18 @@ def mla_decode_packed(p, x, cfg, cache, seg, pos, dec, *, rns=None):
     with jax.named_scope("qkv"):
         q_nope, q_rope, c_kv_t, k_rope_t = _mla_proj_at(p, x, cfg, pos[None],
                                                         rns)
+    layer, bt = cache["layer"], cache["block_table"]
     with jax.named_scope("kv_write"):
-        ckv_pages = write_packed_tokens(cache["ckv_pages"],
-                                        cache["block_table"],
+        ckv_pages = write_packed_tokens(cache["ckv_pages"], layer, bt,
                                         seg, pos, c_kv_t[0])
-        krope_pages = write_packed_tokens(cache["krope_pages"],
-                                          cache["block_table"],
+        krope_pages = write_packed_tokens(cache["krope_pages"], layer, bt,
                                           seg, pos, k_rope_t[0])
     with jax.named_scope("attn_gather"):
-        R = cache["block_table"].shape[0]
-        segc = jnp.clip(seg, 0, R - 1)
-        c_kv = gather_pages(ckv_pages, cache["block_table"])[segc]  # [N,S,r]
-        k_rope = gather_pages(krope_pages,
-                              cache["block_table"])[segc]           # [N,S,dr]
+        segc = jnp.clip(seg, 0, bt.shape[0] - 1)
+        c_kv = gather_pages(ckv_pages, layer, bt,
+                            c_kv_t.shape[2:])[segc]                 # [N,S,r]
+        k_rope = gather_pages(krope_pages, layer, bt,
+                              k_rope_t.shape[2:])[segc]             # [N,S,dr]
     with jax.named_scope("attn"):
         qn = q_nope[0][:, None]                                 # [N,1,H,dn]
         qr = q_rope[0][:, None]

@@ -438,9 +438,12 @@ def _cache_lengths(cache):
 def _cache_smax(cfg, cache):
     first = cache[next(iter(cache))]
     if "block_table" in first:      # paged: capacity = max_blocks * page_size
+        from repro.serve.kv_cache import page_feat
+
         for k, v in first.items():
             if k.endswith("_pages"):
-                return first["block_table"].shape[-1] * v.shape[2]
+                bs = v.shape[-1] // int(np.prod(page_feat(cfg, k)))
+                return first["block_table"].shape[-1] * bs
     for k, v in first.items():
         if k in ("k", "c_kv"):
             return v.shape[2]

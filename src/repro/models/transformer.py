@@ -260,6 +260,10 @@ def _apply_layer(lp, h, cfg, layer_type, mlp_type, *, mode, positions,
 
 
 # ------------------------------------------------------------- the stack ---
+def _is_pool(name: str) -> bool:
+    return name.endswith("_pages")
+
+
 def apply_blocks(blocks, h, cfg, *, mode, positions=None, kv_mask=None,
                  enc_out=None, cache=None, enc: bool = False, chunk=1024,
                  packed=None):
@@ -267,6 +271,15 @@ def apply_blocks(blocks, h, cfg, *, mode, positions=None, kv_mask=None,
 
     ``packed``: optional ``(seg, pos, dec)`` per-token coordinates for
     the mixed chunked-prefill/decode step (decode mode, paged caches).
+
+    A paged cache's page pools (its ``*_pages`` arrays) ride in the
+    scan's carry, whole, and the period index is scanned beside the
+    per-layer tables: each layer writes its new tokens and gathers its
+    rows' pages at ``(layer, page)`` of the carried pool in place
+    (serve/kv_cache.py).  Scanning the pools as ``xs``/``ys`` would
+    slice each layer's pool out, relayout it and stack a fresh copy of
+    the whole pool every step.  Dense caches, SSM states and prefill's
+    new KV keep the plain ``xs``/``ys`` scan.
     """
     L = cfg.n_enc_layers if enc else cfg.n_layers
     ltypes = ("attn",) * L if enc else cfg.layer_types
@@ -274,28 +287,44 @@ def apply_blocks(blocks, h, cfg, *, mode, positions=None, kv_mask=None,
     p = cfg.period if not enc else 1
     enc_dec_dec = cfg.enc_dec and not enc
 
+    pools = {}
+    if mode == "decode" and cache is not None:
+        pools = {lj: {k: v for k, v in c.items() if _is_pool(k)}
+                 for lj, c in cache.items()}
+        pools = {lj: pl for lj, pl in pools.items() if pl}
+        cache = {lj: {k: v for k, v in c.items() if not _is_pool(k)}
+                 for lj, c in cache.items()}
+
     def period_body(carry, xs):
-        h, aux = carry
+        h, aux, pools = carry
         from repro.distributed.sharding import constrain
 
         h = constrain(h, ("batch", None, None))
         bp = xs["params"]
         cslice = xs.get("cache")
+        pools = dict(pools)
         new_cs, pkvs = {}, {}
         for j in range(p):
             lt, mt = ltypes[j], mtypes[j]
-            c_j = cslice[f"l{j}"] if cslice is not None else None
+            lj = f"l{j}"
+            c_j = cslice[lj] if cslice is not None else None
+            if lj in pools:
+                c_j = dict(c_j, **pools[lj], layer=xs["layer"])
             h, nc, pkv, a = _apply_layer(
-                bp[f"l{j}"], h, cfg, lt, mt, mode=mode, positions=positions,
+                bp[lj], h, cfg, lt, mt, mode=mode, positions=positions,
                 kv_mask=kv_mask, enc_out=enc_out, cache=c_j, chunk=chunk,
                 packed=packed)
             aux = aux + a
             if nc is not None:
-                new_cs[f"l{j}"] = nc
+                if lj in pools:
+                    pools[lj] = {k: nc[k] for k in pools[lj]}
+                    nc = {k: v for k, v in nc.items()
+                          if k != "layer" and not _is_pool(k)}
+                new_cs[lj] = nc
             if pkv is not None:
-                pkvs[f"l{j}"] = pkv
+                pkvs[lj] = pkv
         out = new_cs if mode == "decode" else pkvs
-        return (h, aux), out
+        return (h, aux, pools), out
 
     if cfg.remat == "full" and mode == "train":
         period_body = jax.checkpoint(period_body)
@@ -303,5 +332,10 @@ def apply_blocks(blocks, h, cfg, *, mode, positions=None, kv_mask=None,
     xs = {"params": blocks}
     if cache is not None:
         xs["cache"] = cache
-    (h, aux), ys = jax.lax.scan(period_body, (h, jnp.zeros((), jnp.float32)), xs)
+    if pools:
+        xs["layer"] = jnp.arange(L // p, dtype=jnp.int32)
+    (h, aux, pools), ys = jax.lax.scan(
+        period_body, (h, jnp.zeros((), jnp.float32), pools), xs)
+    for lj, pl in pools.items():
+        ys[lj] = dict(ys[lj], **pl)
     return h, ys, aux

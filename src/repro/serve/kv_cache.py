@@ -10,22 +10,32 @@ jitted decode step — the step's shapes depend only on ``(max_seqs,
 max_blocks, page_size)``, never on any prompt length — and a finished
 row's pages return to the pool immediately.
 
-Layout per attention layer (leading ``n_periods`` dim added by the scan
-stacking, exactly like the dense cache):
+Layout per attention layer: each page is stored flat, as ``bs·F``
+contiguous values (``page_size`` token rows of ``F`` features each), with
+a leading ``n_periods`` dim added by the scan stacking:
 
-  * ``attn``: ``k_pages`` / ``v_pages``  ``[n_pages, page_size, Hk, D]``
-  * ``mla``:  ``ckv_pages`` ``[n_pages, page_size, r]``,
-              ``krope_pages`` ``[n_pages, page_size, dr]``
-  * both:     ``block_table`` ``[max_seqs, max_blocks]`` int32,
-              ``lengths`` ``[max_seqs]`` int32
+  * ``attn``: ``k_pages`` / ``v_pages``  ``[n_periods, n_pages, bs·Hk·D]``
+  * ``mla``:  ``ckv_pages`` ``[n_periods, n_pages, bs·r]``,
+              ``krope_pages`` ``[n_periods, n_pages, bs·dr]``
+  * both:     ``block_table`` ``[n_periods, max_seqs, max_blocks]`` int32,
+              ``lengths`` ``[n_periods, max_seqs]`` int32
+
+The flat minor dim is a multiple of 128 at real widths, so the TPU
+compiler tiles the pool without padding and keeps it row-major: token
+``t`` of a page is the ``F`` values at ``(t % bs)·F``.  :func:`page_view`
+and :func:`flat_pages` turn a page into its ``[bs, *feat]`` token rows
+and back; every helper here goes through them or writes rows at that
+offset.  The layer loop carries the whole stacked pool and each layer
+writes and gathers at ``(layer, page)`` in place, so no step slices out
+or copies a layer's pool (models/transformer.py ``apply_blocks``).
 
 Physical page 0 is the **trash page**: the block-table entries of empty
 slots (and of logical blocks past a sequence's end) point at it, so every
 gather/scatter stays in bounds with no per-row branching — reads through
 it are masked by ``lengths`` and writes to it are discarded garbage.
 
-The device-side helpers here (:func:`gather_pages`, :func:`write_token`,
-:func:`write_prompt_pages`) are pure jnp and are consumed by
+The device-side helpers here (:func:`gather_pages`, the ``write_*``
+helpers, :func:`copy_pages`) are pure jnp and are consumed by
 ``models/attention.py``; the host-side :class:`PageAllocator` free list
 is consumed by ``serve/scheduler.py``.
 """
@@ -34,6 +44,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -45,6 +56,9 @@ __all__ = [
     "PageAllocator",
     "PrefixCache",
     "make_paged_cache",
+    "page_feat",
+    "page_view",
+    "flat_pages",
     "set_tables",
     "gather_pages",
     "write_token",
@@ -352,20 +366,40 @@ class PrefixCache:
 
 
 # ------------------------------------------------------- device pytrees ---
+def page_feat(cfg, name: str) -> tuple[int, ...]:
+    """Per-token feature shape of the pool ``name`` of a paged cache."""
+    if name in ("k_pages", "v_pages"):
+        return (cfg.n_kv_heads, cfg.d_head)
+    if name == "ckv_pages":
+        return (cfg.mla.kv_lora_rank,)
+    if name == "krope_pages":
+        return (cfg.mla.qk_rope_dim,)
+    raise KeyError(name)
+
+
+def page_view(pages, feat):
+    """Flat pages ``[..., bs·F]`` as token rows ``[..., bs, *feat]``."""
+    return pages.reshape(pages.shape[:-1] + (-1,) + tuple(feat))
+
+
+def flat_pages(rows, feat):
+    """Token rows ``[..., bs, *feat]`` as flat pages ``[..., bs·F]`` (the
+    inverse of :func:`page_view`)."""
+    return rows.reshape(rows.shape[:rows.ndim - len(feat) - 1] + (-1,))
+
+
 def _layer_pages(cfg, lt: str, pcfg: PagedCacheConfig, dtype):
-    P, bs = pcfg.n_pages, pcfg.page_size
     if lt == "attn":
-        kv = (P, bs, cfg.n_kv_heads, cfg.d_head)
-        return {"k_pages": jnp.zeros(kv, dtype), "v_pages": jnp.zeros(kv, dtype)}
-    if lt == "mla":
-        m = cfg.mla
-        return {
-            "ckv_pages": jnp.zeros((P, bs, m.kv_lora_rank), dtype),
-            "krope_pages": jnp.zeros((P, bs, m.qk_rope_dim), dtype),
-        }
-    raise NotImplementedError(
-        f"paged serving supports attn/mla layers only, got {lt!r} "
-        "(SSM states are fixed-size per sequence — nothing to page)")
+        names = ("k_pages", "v_pages")
+    elif lt == "mla":
+        names = ("ckv_pages", "krope_pages")
+    else:
+        raise NotImplementedError(
+            f"paged serving supports attn/mla layers only, got {lt!r} "
+            "(SSM states are fixed-size per sequence — nothing to page)")
+    return {n: jnp.zeros((pcfg.n_pages, pcfg.page_size
+                          * math.prod(page_feat(cfg, n))), dtype)
+            for n in names}
 
 
 def make_paged_cache(cfg, pcfg: PagedCacheConfig, *, dtype=jnp.bfloat16):
@@ -415,59 +449,86 @@ def set_tables(cache, block_tables, lengths):
 
 
 # ------------------------------------------------------ gather / scatter --
-def gather_pages(pages, block_table):
-    """[P, bs, ...] pages + [R, nb] table -> dense [R, nb*bs, ...] view.
+# Every helper below takes the stacked pool ``[n_periods, P, bs·F]`` and
+# the period index ``layer`` it reads or writes, so the layer loop can
+# carry the pool and update it in place.
 
-    Logical token position t of row r lives at
-    ``pages[block_table[r, t // bs], t % bs]``; the gather lays rows out
-    contiguously so downstream attention is *identical* to the dense-cache
-    path (bit for bit — asserted in tests/test_kv_cache.py).
+def gather_pages(pages, layer, block_table, feat):
+    """Pool + [R, nb] table -> dense [R, nb*bs, *feat] view of ``layer``.
+
+    Logical token position t of row r lives at token row ``t % bs`` of
+    page ``block_table[r, t // bs]``; one gather at ``(layer,
+    block_table)`` reads the rows' pages straight from the stacked pool
+    and lays them out contiguously, so downstream attention is
+    *identical* to the dense-cache path (bit for bit — asserted in
+    tests/test_kv_cache.py).
     """
     R = block_table.shape[0]
-    g = pages[block_table]                      # [R, nb, bs, ...]
-    return g.reshape((R, -1) + pages.shape[2:])
+    g = page_view(pages[layer, block_table], feat)    # [R, nb, bs, *feat]
+    return g.reshape((R, -1) + tuple(feat))
 
 
-def write_token(pages, block_table, lengths, vals):
+def _write_rows(pages, layer, page, off, vals):
+    """Write token row m of ``vals`` [M, *feat] at row ``off[m]`` of page
+    ``page[m]`` of ``layer``: ``F`` contiguous values at ``off[m]·F``,
+    one scatter into the pool, which XLA updates in place."""
+    M = page.shape[0]
+    rows = vals.reshape(M, -1).astype(pages.dtype)
+    idx = jnp.stack([jnp.broadcast_to(layer, (M,)), page,
+                     off * rows.shape[1]], axis=1).astype(jnp.int32)
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,), inserted_window_dims=(0, 1),
+        scatter_dims_to_operand_dims=(0, 1, 2))
+    return jax.lax.scatter(pages, idx, rows, dnums,
+                           mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+
+def _page_size(pages, feat):
+    return pages.shape[-1] // math.prod(feat)
+
+
+def write_token(pages, layer, block_table, lengths, vals):
     """Scatter one new token per row at its current length.
 
-    ``vals`` [R, ...]: row r goes to page ``block_table[r, lengths[r] //
+    ``vals`` [R, *feat]: row r goes to page ``block_table[r, lengths[r] //
     bs]`` offset ``lengths[r] % bs``.  Rows whose tables point at the
     trash page (inactive slots) write garbage there harmlessly — and a
     row somehow past capacity (block index >= nb) is *redirected* to the
     trash page rather than clipped onto a real page, so a scheduler bug
     can never corrupt a live token.
     """
-    bs = pages.shape[1]
+    bs = _page_size(pages, vals.shape[1:])
     blk = lengths // bs
     page = jnp.take_along_axis(block_table, blk[:, None], axis=1,
                                mode="fill", fill_value=TRASH_PAGE)[:, 0]
-    return pages.at[page, lengths % bs].set(vals.astype(pages.dtype))
+    return _write_rows(pages, layer, page, lengths % bs, vals)
 
 
-def write_token_window(pages, block_table, lengths, vals):
+def write_token_window(pages, layer, block_table, lengths, vals):
     """Scatter W consecutive tokens per row starting at its length.
 
-    ``vals`` [R, W, ...] (a speculative-verify window): token i of row r
-    goes to logical position ``lengths[r] + i``.  Like
+    ``vals`` [R, W, *feat] (a speculative-verify window): token i of row
+    r goes to logical position ``lengths[r] + i``.  Like
     :func:`write_token`, positions past the row's table (block index >=
     nb) or on unallocated blocks redirect to the trash page, so draft
     tokens past a row's pages lose their KV harmlessly — the engine caps
     acceptance to what landed on real pages.
     """
-    bs = pages.shape[1]
-    W = vals.shape[1]
+    bs = _page_size(pages, vals.shape[2:])
+    R, W = vals.shape[:2]
     pos = lengths[:, None] + jnp.arange(W)[None]            # [R, W]
     page = jnp.take_along_axis(block_table, pos // bs, axis=1,
                                mode="fill", fill_value=TRASH_PAGE)
-    return pages.at[page, pos % bs].set(vals.astype(pages.dtype))
+    return _write_rows(pages, layer, page.reshape(-1),
+                       (pos % bs).reshape(-1),
+                       vals.reshape((R * W,) + vals.shape[2:]))
 
 
-def write_packed_tokens(pages, block_table, seg, pos, vals):
+def write_packed_tokens(pages, layer, block_table, seg, pos, vals):
     """Scatter N packed tokens at explicit (segment, position) coords.
 
-    ``vals`` [N, ...] (a mixed chunked-prefill/decode step): token i goes
-    to logical position ``pos[i]`` of row ``seg[i]`` — physical page
+    ``vals`` [N, *feat] (a mixed chunked-prefill/decode step): token i
+    goes to logical position ``pos[i]`` of row ``seg[i]`` — physical page
     ``block_table[seg[i], pos[i] // bs]`` offset ``pos[i] % bs``.  Unlike
     :func:`write_token`/:func:`write_token_window`, each token carries
     its own segment and position, so one scatter serves any mix of
@@ -475,20 +536,20 @@ def write_packed_tokens(pages, block_table, seg, pos, vals):
     are redirected to the trash page, as are positions past a row's
     table (block index >= nb) — invalid lanes lose their KV harmlessly.
     """
-    bs = pages.shape[1]
+    bs = _page_size(pages, vals.shape[1:])
     R = block_table.shape[0]
     segc = jnp.clip(seg, 0, R - 1)
     page = jnp.take_along_axis(block_table[segc], (pos // bs)[:, None],
                                axis=1, mode="fill",
                                fill_value=TRASH_PAGE)[:, 0]
     page = jnp.where(seg >= 0, page, TRASH_PAGE)
-    return pages.at[page, pos % bs].set(vals.astype(pages.dtype))
+    return _write_rows(pages, layer, page, pos % bs, vals)
 
 
 def copy_pages(pages, src, dst):
-    """Copy page contents src[i] -> dst[i] (periods-stacked pool).
+    """Copy page contents src[i] -> dst[i] in every layer of the pool.
 
-    ``pages`` [n_periods, P, bs, ...]; ``src``/``dst`` [m] int32.  The
+    ``pages`` [n_periods, P, bs·F]; ``src``/``dst`` [m] int32.  The
     copy-on-write split: a sequence about to write into a shared page
     first duplicates it onto a fresh page and repoints its block table.
     No-op rows pass ``src = dst = TRASH_PAGE`` (trash copies onto trash),
@@ -498,15 +559,16 @@ def copy_pages(pages, src, dst):
 
 
 def write_prompt_pages(pages, block_row, planes):
-    """Blit one prefilled prompt into its pages (periods-stacked).
+    """Blit one prefilled prompt into its pages in every layer.
 
-    ``pages`` [n_periods, P, bs, ...]; ``block_row`` [nbp] physical page
-    per logical block (trash for blocks past the prompt); ``planes``
-    [n_periods, 1, Tpad, ...] with ``Tpad == nbp * bs``.  Whole pages are
-    overwritten — positions past the prompt length hold garbage that
+    ``pages`` [n_periods, P, bs·F]; ``block_row`` [nbp] physical page per
+    logical block (trash for blocks past the prompt); ``planes``
+    [n_periods, 1, Tpad, *feat] with ``Tpad == nbp * bs``.  Whole pages
+    are overwritten — positions past the prompt length hold garbage that
     ``lengths`` masks at read time.
     """
-    npr, P, bs = pages.shape[:3]
+    npr, Tpad = planes.shape[0], planes.shape[2]
     nbp = block_row.shape[0]
-    v = planes.reshape((npr, nbp, bs) + planes.shape[3:])
+    feat = planes.shape[3:]
+    v = flat_pages(planes.reshape((npr, nbp, Tpad // nbp) + feat), feat)
     return pages.at[:, block_row].set(v.astype(pages.dtype))

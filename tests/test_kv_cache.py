@@ -9,7 +9,9 @@ from repro.serve.kv_cache import (
     TRASH_PAGE,
     PageAllocator,
     PagedCacheConfig,
+    flat_pages,
     gather_pages,
+    page_view,
     write_prompt_pages,
     write_token,
 )
@@ -53,7 +55,8 @@ def test_paged_config_validates():
 
 
 def _paged_from_dense(dense, bs, rng):
-    """Scatter a dense [R, S, ...] cache into a shuffled page pool."""
+    """Scatter a dense [R, S, ...] cache into a shuffled one-layer pool
+    of flat pages [1, P, bs*F]."""
     R, S = dense.shape[:2]
     nb = S // bs
     perm = rng.permutation(np.arange(1, 1 + R * nb))
@@ -62,14 +65,15 @@ def _paged_from_dense(dense, bs, rng):
     for r in range(R):
         for b in range(nb):
             pages[bt[r, b]] = dense[r, b * bs:(b + 1) * bs]
-    return jnp.asarray(pages), jnp.asarray(bt)
+    pool = flat_pages(pages, dense.shape[2:])[None]
+    return jnp.asarray(pool), jnp.asarray(bt)
 
 
 def test_gather_pages_equals_dense_bitwise():
     rng = np.random.default_rng(0)
     dense = rng.standard_normal((3, 32, 2, 4)).astype(np.float32)
     pages, bt = _paged_from_dense(dense, bs=8, rng=rng)
-    out = np.asarray(gather_pages(pages, bt))
+    out = np.asarray(gather_pages(pages, 0, bt, (2, 4)))
     assert out.shape == dense.shape
     assert (out == dense).all()     # bit-for-bit
 
@@ -90,39 +94,42 @@ def test_paged_attention_read_equals_dense_bitwise():
     for r in range(R):
         for b in range(S // 16):
             vpages[btn[r, b]] = dense_v[r, b * 16:(b + 1) * 16]
-    vp = jnp.asarray(vpages)
+    vp = jnp.asarray(flat_pages(vpages, (Hk, D))[None])
     out_d, lse_d = decode_attention(q, jnp.asarray(dense_k),
                                     jnp.asarray(dense_v), lengths)
-    out_p, lse_p = decode_attention(q, gather_pages(kp, bt),
-                                    gather_pages(vp, bt), lengths)
+    out_p, lse_p = decode_attention(q, gather_pages(kp, 0, bt, (Hk, D)),
+                                    gather_pages(vp, 0, bt, (Hk, D)),
+                                    lengths)
     assert (np.asarray(out_d) == np.asarray(out_p)).all()
     assert (np.asarray(lse_d) == np.asarray(lse_p)).all()
 
 
 def test_write_token_lands_at_length():
     rng = np.random.default_rng(2)
-    R, nb, bs = 3, 2, 4
-    pages = jnp.zeros((1 + R * nb, bs, 2), jnp.float32)
+    R, nb, bs, layers = 3, 2, 4, 3
+    pages = jnp.zeros((layers, 1 + R * nb, bs * 2), jnp.float32)
     bt = jnp.asarray(np.arange(1, 1 + R * nb).reshape(R, nb), jnp.int32)
     lengths = jnp.asarray([0, 3, 5], jnp.int32)     # row 2 in block 1
     vals = jnp.asarray(rng.standard_normal((R, 2)), jnp.float32)
-    pages = write_token(pages, bt, lengths, vals)
-    dense = np.asarray(gather_pages(pages, bt))     # [R, nb*bs, 2]
+    pages = write_token(pages, 1, bt, lengths, vals)
+    dense = np.asarray(gather_pages(pages, 1, bt, (2,)))  # [R, nb*bs, 2]
     for r, t in enumerate([0, 3, 5]):
         assert (dense[r, t] == np.asarray(vals)[r]).all()
         mask = np.ones(nb * bs, bool)
         mask[t] = False
         assert (dense[r, mask] == 0).all()          # nothing else touched
+    # the other layers of the stacked pool are not touched either
+    assert (np.asarray(pages)[[0, 2]] == 0).all()
 
 
 def test_write_prompt_pages_blits_and_trash_pads():
     rng = np.random.default_rng(3)
     npr, P, bs, d = 2, 6, 4, 3
-    pages = jnp.zeros((npr, P, bs, d), jnp.float32)
+    pages = jnp.zeros((npr, P, bs * d), jnp.float32)
     planes = jnp.asarray(rng.standard_normal((npr, 1, 8, d)), jnp.float32)
     block_row = jnp.asarray([2, 5], jnp.int32)
     pages = write_prompt_pages(pages, block_row, planes)
-    got = np.asarray(pages)
+    got = np.asarray(page_view(pages, (d,)))
     want = np.asarray(planes).reshape(npr, 2, bs, d)
     assert (got[:, 2] == want[:, 0]).all()
     assert (got[:, 5] == want[:, 1]).all()
@@ -130,4 +137,4 @@ def test_write_prompt_pages_blits_and_trash_pads():
     # unused logical blocks redirect to trash: in-bounds, harmless
     trash_row = jnp.asarray([1, TRASH_PAGE], jnp.int32)
     pages2 = write_prompt_pages(pages, trash_row, planes)
-    assert (np.asarray(pages2)[:, 1] == want[:, 0]).all()
+    assert (np.asarray(page_view(pages2, (d,)))[:, 1] == want[:, 0]).all()

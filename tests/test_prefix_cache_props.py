@@ -107,23 +107,23 @@ def test_cow_copy_preserves_other_holders_bitwise(seed):
     write."""
     rng = np.random.default_rng(seed)
     npr, P, bs, d = 2, 6, 4, 3
-    pages = jnp.asarray(rng.standard_normal((npr, P, bs, d)), jnp.float32)
+    pages = jnp.asarray(rng.standard_normal((npr, P, bs * d)), jnp.float32)
     bt_a = np.array([[1, 2]], np.int32)          # A shares page 2 with B
     bt_b = np.array([[3, 2]], np.int32)
-    before_a = np.asarray(gather_pages(pages[0], jnp.asarray(bt_a)))
-    before_b = np.asarray(gather_pages(pages[0], jnp.asarray(bt_b)))
+    before_a = np.asarray(gather_pages(pages, 0, jnp.asarray(bt_a), (d,)))
+    before_b = np.asarray(gather_pages(pages, 0, jnp.asarray(bt_b), (d,)))
     # split for B: copy page 2 -> fresh page 4, repoint B only
     src = jnp.asarray([2, TRASH_PAGE], jnp.int32)
     dst = jnp.asarray([4, TRASH_PAGE], jnp.int32)
     pages2 = copy_pages(pages, src, dst)
     bt_b2 = np.array([[3, 4]], np.int32)
-    after_a = np.asarray(gather_pages(pages2[0], jnp.asarray(bt_a)))
-    after_b = np.asarray(gather_pages(pages2[0], jnp.asarray(bt_b2)))
+    after_a = np.asarray(gather_pages(pages2, 0, jnp.asarray(bt_a), (d,)))
+    after_b = np.asarray(gather_pages(pages2, 0, jnp.asarray(bt_b2), (d,)))
     assert (before_a == after_a).all()           # frozen original intact
     assert (before_b == after_b).all()           # the copy is exact
     # and a write into B's copy leaves A untouched
     pages3 = pages2.at[:, 4].set(0.0)
-    assert (np.asarray(gather_pages(pages3[0], jnp.asarray(bt_a)))
+    assert (np.asarray(gather_pages(pages3, 0, jnp.asarray(bt_a), (d,)))
             == before_a).all()
 
 

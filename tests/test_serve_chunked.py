@@ -26,10 +26,13 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs.base import get_config
 from repro.core.rns_matmul import RnsDotConfig
 from repro.models import model as M
+from repro.models import transformer as tf
+from repro.serve import kv_cache as kv
 from repro.serve.engine import ContinuousEngine, Engine, ServeConfig
 
 
@@ -173,6 +176,119 @@ def test_ttft_and_phase_stats(gqa_model):
             == sum(len(p) for p in prompts))
     assert (sum(s["decode_tokens"] for s in stats["steps"])
             == stats["total_new_tokens"])
+
+
+# ------------------------------------------ carried page pool ---------
+def _slab_apply_blocks(blocks, h, cfg, *, mode, cache, packed=None):
+    """Reference layer loop over the paged cache: the pools are scanned
+    as ``xs``/``ys``, so each period runs on its own slab of the pool
+    (``[P, bs*F]``, handed to the layer as a one-layer pool) and the
+    slabs are stacked into a fresh pool."""
+    def body(h, xs):
+        new = {}
+        for j in range(cfg.period):
+            lj = f"l{j}"
+            c = {k: v[None] if k.endswith("_pages") else v
+                 for k, v in xs["cache"][lj].items()}
+            h, nc, _, _ = tf._apply_layer(
+                xs["params"][lj], h, cfg, cfg.layer_types[j],
+                cfg.mlp_types[j], mode=mode, positions=None, kv_mask=None,
+                enc_out=None, cache=dict(c, layer=0), packed=packed)
+            new[lj] = {k: v[0] if k.endswith("_pages") else v
+                       for k, v in nc.items() if k != "layer"}
+        return h, new
+
+    h, ys = jax.lax.scan(body, h, {"params": blocks, "cache": cache})
+    return h, ys, jnp.zeros((), jnp.float32)
+
+
+def _paged_inputs(cfg, kind, rng):
+    """A random pool, block tables over 3 slots (slot 1 empty for the
+    decode kinds), and the step's inputs.  Returns (cache, args,
+    written) with ``written`` the (page, row) pairs of valid tokens."""
+    bs, nb, R = 4, 4, 3
+    pcfg = kv.PagedCacheConfig(page_size=bs, n_pages=1 + R * nb + 3,
+                               max_seqs=R, max_blocks=nb)
+    cache = kv.make_paged_cache(cfg, pcfg, dtype=jnp.float32)
+    cache = jax.tree.map(
+        lambda x: jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+        if x.dtype == jnp.float32 else x, cache)
+    bt = rng.permutation(np.arange(1, pcfg.n_pages))[:R * nb]
+    bt = bt.reshape(R, nb).astype(np.int32)
+    lengths = np.array([9, 5, 2], np.int32)
+    if kind == "mixed_step":
+        # slot 0 and 2 decode at their lengths, slot 1 prefills a chunk
+        # over positions 4..11; pad lanes (seg -1) aim at slot 0's live
+        # positions 0..3 and must land on the trash page instead
+        seg = np.array([0] + [1] * 8 + [2] + [-1] * 6, np.int32)
+        pos = np.array([9] + list(range(4, 12)) + [2] + [0, 1, 2, 3, 0, 1],
+                       np.int32)
+        dec = np.array([True] + [False] * 8 + [True] + [False] * 6)
+        valid = seg >= 0
+        tokens = rng.integers(1, cfg.vocab, seg.shape).astype(np.int32)
+        args = tuple(map(jnp.asarray, (tokens, seg, pos, dec, valid)))
+        written = {(bt[s, p // bs], p % bs)
+                   for s, p in zip(seg[valid], pos[valid])}
+    else:
+        W = 1 if kind == "decode_step" else 3
+        bt[1] = kv.TRASH_PAGE               # an empty slot
+        active = np.array([True, False, True])
+        tokens = rng.integers(1, cfg.vocab, (R, W)).astype(np.int32)
+        args = (jnp.asarray(tokens), jnp.asarray(active))
+        written = {(bt[r, p // bs], p % bs) for r in (0, 2)
+                   for p in range(lengths[r], lengths[r] + W)}
+    cache = kv.set_tables(cache, bt, lengths)
+    return cache, args, written
+
+
+def _run_step(params, cfg, kind, cache, args):
+    if kind == "mixed_step":
+        fn = lambda p, c, *a: M.mixed_step(p, cfg, *a, c)       # noqa: E731
+    elif kind == "decode_step":
+        fn = lambda p, c, t, act: M.decode_step(p, cfg, t, c,   # noqa: E731
+                                                active=act)
+    else:
+        fn = lambda p, c, t, act: M.decode_window(p, cfg, t, c,  # noqa: E731
+                                                  active=act)
+    return jax.jit(fn)(params, cache, *args)
+
+
+@pytest.mark.parametrize("kind", ["mixed_step", "decode_step",
+                                  "decode_window"])
+@pytest.mark.parametrize("layer", ["attn", "mla"])
+def test_carried_pool_step_bitwise(gqa_model, mla_model, monkeypatch,
+                                   layer, kind):
+    """The layer loop carries the whole page pool and updates it in
+    place; every paged step then equals, bitwise, the loop that scans
+    each layer's slab of the pool: the same logits and the same pool.
+    A step changes only the rows its valid tokens write and the trash
+    page: pad lanes and empty slots land on trash, every other page
+    keeps its bytes."""
+    cfg, params = gqa_model if layer == "attn" else mla_model
+    cache, args, written = _paged_inputs(cfg, kind,
+                                         np.random.default_rng(23))
+    logits, new = _run_step(params, cfg, kind, cache, args)
+    monkeypatch.setattr(tf, "apply_blocks", _slab_apply_blocks)
+    want_logits, want = _run_step(params, cfg, kind, cache, args)
+
+    assert (np.asarray(logits) == np.asarray(want_logits)).all()
+    assert jax.tree.structure(new) == jax.tree.structure(want)
+    for lj, c in new.items():
+        for name, pool in c.items():
+            if not name.endswith("_pages"):
+                assert (np.asarray(pool) == np.asarray(want[lj][name])).all()
+                continue
+            feat = kv.page_feat(cfg, name)
+            got = np.asarray(kv.page_view(pool, feat))  # [n, P, bs, *feat]
+            assert (got == np.asarray(kv.page_view(want[lj][name],
+                                                   feat))).all(), name
+            before = np.asarray(kv.page_view(cache[lj][name], feat))
+            changed = (got != before).reshape(got.shape[:3] + (-1,))
+            changed = changed.any(axis=(0, 3))           # [P, bs]
+            rows = {(int(p), int(r)) for p, r in zip(*np.nonzero(changed))}
+            assert rows - {(kv.TRASH_PAGE, r) for r in range(got.shape[2])} \
+                == written, name
+            assert changed[kv.TRASH_PAGE].any(), name    # pad/empty lanes
 
 
 # ------------------------------------------- cross-feature validation ---
