@@ -15,7 +15,6 @@ from __future__ import annotations
 import numpy as np
 
 from chipbench import reference
-from chipbench.model import Dims
 
 BATCH = 4
 
@@ -54,13 +53,16 @@ def batches(finished: dict, rids: list, length: int):
         yield tokens, targets
 
 
-def widest_gaps(params, dm: Dims, finished: dict, rids: list, length: int,
-                bits: int | None = None) -> tuple[float, float | None, int]:
-    """(served gap, control gap or None, served tokens compared)."""
+def widest_gaps(params, dm, finished: dict, rids: list, length: int,
+                bits: int | None = None, logits=reference.logits
+                ) -> tuple[float, float | None, int]:
+    """(served gap, control gap or None, served tokens compared), by the
+    architecture's reference ``logits`` at its sizes ``dm``."""
     served = ctrl = 0.0
     n = 0
     for tokens, targets in batches(finished, rids, length):
-        g, c = reference.gaps(params, tokens, targets, dm=dm, bits=bits)
+        g, c = reference.gaps(params, tokens, targets, dm=dm, bits=bits,
+                              logits=logits)
         served = max(served, float(np.max(np.asarray(g))))
         if c is not None:
             ctrl = max(ctrl, float(np.max(np.asarray(c))))

@@ -51,7 +51,8 @@ def p95(xs) -> float:
 @dataclasses.dataclass
 class RunData:
     """What a per-layer reader may read."""
-    dims: object            # model.Dims
+    arch: object            # the cell's archs/<arch>.py
+    dims: object            # its dims() of the configuration
     budget: int             # packed lanes per step
     digits: int             # residue digits of the profile
     peak: dict              # the device's row of device.PEAKS

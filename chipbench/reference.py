@@ -88,12 +88,15 @@ def logits(params, dm: Dims, tokens, bits: int | None = None):
         return h @ params["lm_head"]["w"]
 
 
-@functools.partial(jax.jit, static_argnames=("dm", "bits"))
-def gaps(params, tokens, targets, dm: Dims, bits: int | None = None):
+@functools.partial(jax.jit, static_argnames=("dm", "bits", "logits"))
+def gaps(params, tokens, targets, dm, bits: int | None = None,
+         logits=logits):
     """Per position, how far the reference's logit of a token lies below
     its best.
 
     ``targets`` [B, T] are the served tokens (-1 where none is served).
+    ``logits`` is the architecture's reference (``archs/<arch>.py``; the
+    dense one by default) and ``dm`` its sizes.
     Returns (served_gap [B, T], control_gap [B, T] or None): the gap of
     each served token, and with ``bits`` the gap of the token that the
     ``bits``-bit control puts first at the same position."""

@@ -38,7 +38,6 @@ sys.path.insert(1, str(ROOT / "src"))
 
 from chipbench import (  # noqa: E402
     check, device, loop, measure, spec, traffic, xtrace)
-from chipbench.model import Dims, make_weights, program_config  # noqa: E402
 
 TRACE_SECONDS = 4.0     # length of the traced end of the window
 CONTROL_BITS = 4        # the control's MLP operand bits (int4 for int8)
@@ -86,7 +85,7 @@ class Tracer:
             shutil.rmtree(self.dir, ignore_errors=True)
 
 
-def warm_up(engine, rng, dm: Dims, budget: int) -> None:
+def warm_up(engine, rng, dm, budget: int) -> None:
     """One request through every program the window uses: two prompt
     chunks, then decode steps."""
     engine.submit(rng.integers(0, dm.vocab, budget + 1).astype("int32"), 3)
@@ -99,15 +98,16 @@ def warm_up(engine, rng, dm: Dims, budget: int) -> None:
 
 def build(cell, seed: int, hook=None):
     """Weights, the engine as the configuration states, and one warm-up
-    request through it."""
+    request through it; the sizes, weights and program config come from
+    the cell's architecture module."""
     import jax
     import numpy as np
 
     from repro.serve import engine as engine_mod
 
-    dm = Dims.of(cell.config)
-    cfg, scfg = program_config(cell.config)
-    params = jax.block_until_ready(make_weights(dm, seed))
+    dm = cell.arch.dims(cell.config)
+    cfg, scfg = cell.arch.program_config(cell.config)
+    params = jax.block_until_ready(cell.arch.make_weights(dm, seed))
     engine = engine_mod.ContinuousEngine(params, cfg, scfg)
     if hook is not None:
         hook(engine)
@@ -200,7 +200,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         # host-clock readers see the window up to the profiler's start
         untraced = loop.Window(win.start, tracer.started or win.end)
         data = measure.RunData(
-            dims=dm, budget=scfg.token_budget,
+            arch=cell.arch, dims=dm, budget=scfg.token_budget,
             digits=_digits(cfg.rns.profile), peak=device.peaks(dev["kind"]),
             win=untraced, states=states, steps=steps, spans=spans, trace=tr)
         for m in cell.per_layer:
@@ -229,7 +229,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                         int(cell.config["check"]["sample_tokens"]))
     t_check = loop.clock()
     gap, ctrl, n = check.widest_gaps(params, dm, finished, rids,
-                                     scfg.max_cache, bits=control_bits)
+                                     scfg.max_cache, bits=control_bits,
+                                     logits=cell.arch.logits)
     log(f"[check] requests={len(rids)} served_tokens={n} "
         f"seconds={loop.clock() - t_check:.3f} run_s={end - T_START:.3f}")
     correct = n > 0 and gap <= limit

@@ -42,7 +42,8 @@ def _tiny_cell():
         end_to_end=[m for m in BENCH["end_to_end"]
                     if spec._reports(m, "smollm-135m.rns9.chat")],
         per_layer=[m for m in BENCH["per_layer"]
-                   if spec._reports(m, "smollm-135m.rns9.chat")])
+                   if spec._reports(m, "smollm-135m.rns9.chat")],
+        arch=spec.arch(f))
 
 
 def _token_altered(engine):

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from chipbench import loop, measure, progprobe, progtrace, spec, xtrace
+from chipbench.archs import dense
 from chipbench.device import PEAKS
 from chipbench.loop import Spans, Window
 from chipbench.model import Dims
@@ -46,7 +47,7 @@ def _trace(ops, spans=(), modules=(), lo=0.0, hi=100.0):
 
 def _run(program=None, trace=None):
     run = measure.RunData(
-        dims=SMOLLM, budget=64, digits=9, peak=PEAKS["TPU v5 lite"],
+        arch=dense, dims=SMOLLM, budget=64, digits=9, peak=PEAKS["TPU v5 lite"],
         win=Window(10.0, 20.0), states=[], steps=[], spans=Spans(),
         trace=trace)
     if program is not None:

@@ -12,7 +12,6 @@ import numpy as np
 import pytest
 
 from chipbench import device, measure, spec, traffic
-from chipbench.model import Dims
 
 from conftest import ROOT
 
@@ -144,14 +143,14 @@ def test_benchmark_json_keeps_to_its_shape():
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
 def test_configuration_builds_the_program_config(name):
-    from chipbench.model import program_config, weight_shapes
-
+    """Through the architecture the file names, so that a configuration
+    added later is covered as it is."""
     f = json.loads((ROOT / "chipbench" / "configs" / f"{name}.json")
                    .read_text())
-    cfg, scfg = program_config(f)
-    dm = Dims.of(f)
-    assert (cfg.n_layers, cfg.d_model, cfg.d_ff, cfg.vocab) == (
-        dm.layers, dm.d, dm.ff, dm.vocab)
+    arch = spec.arch(f)
+    cfg, scfg = arch.program_config(f)
+    dm = arch.dims(f)
+    assert (cfg.n_layers, cfg.vocab) == (dm.layers, dm.vocab)
     assert scfg.chunked_prefill and scfg.resident_weights
     assert scfg.rns_backend == "pallas_fused" and not scfg.audit
     # the benchmark's weights have the program's parameter layout
@@ -160,9 +159,9 @@ def test_configuration_builds_the_program_config(name):
 
     want = jax.eval_shape(lambda: M.init_model(jax.random.PRNGKey(0),
                                                cfg)[0])
-    got = weight_shapes(dm)
-    assert jax.tree.structure(want) == jax.tree.structure(
-        got, is_leaf=lambda x: isinstance(x, tuple))
-    assert [w.shape for w in jax.tree.leaves(want)] == jax.tree.leaves(
-        got, is_leaf=lambda x: isinstance(x, tuple))
-    assert np.prod(got["embed"]["table"]) == dm.vocab * dm.d
+    got = jax.eval_shape(lambda: arch.make_weights(dm, 2**31 + 1))
+    assert jax.tree.structure(want) == jax.tree.structure(got)
+    assert [w.shape for w in jax.tree.leaves(want)] == [
+        w.shape for w in jax.tree.leaves(got)]
+    assert all(w.dtype == jax.numpy.float32 for w in jax.tree.leaves(got))
+    assert got["embed"]["table"].shape == (dm.vocab, cfg.d_model)

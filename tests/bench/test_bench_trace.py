@@ -3,6 +3,7 @@
 import pytest
 
 from chipbench import flops, measure, xtrace
+from chipbench.archs import dense
 from chipbench.device import PEAKS
 from chipbench.loop import Spans, StepRecord, Window
 from chipbench.model import Dims
@@ -115,7 +116,7 @@ def test_fused_launches_of_one_packed_step(dm, bound):
 
 def _run_data(trace=None, steps=(), spans=None, states=()):
     return measure.RunData(
-        dims=SMOLLM, budget=64, digits=9, peak=PEAKS["TPU v5 lite"],
+        arch=dense, dims=SMOLLM, budget=64, digits=9, peak=PEAKS["TPU v5 lite"],
         win=Window(0.0, 2.0), states=list(states), steps=list(steps),
         spans=spans or Spans(), trace=trace)
 
